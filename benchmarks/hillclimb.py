@@ -62,10 +62,11 @@ def main():
     tcfg_over, env, approx = parse_variant(args.variant)
     os.environ.update(env)
 
-    # import AFTER env so the sharding-rule toggles are seen, and so the
-    # dryrun module sets the 512-device XLA flag first
+    # import AFTER env so the sharding-rule toggles are seen
     from repro.configs import get_config, shapes_for
-    from repro.launch.dryrun import run_cell
+    from repro.launch.dryrun import force_host_devices, run_cell
+
+    force_host_devices()
 
     cfg = get_config(args.arch)
     shape = next(s for s in shapes_for(cfg) if s.name == args.shape)

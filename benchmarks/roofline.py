@@ -22,9 +22,8 @@ emulated decode hot path away from the memory roofline, toward compute.
       --seq 4096 --batch 64 --mesh single --out results/roofline.json
 
 No pre-existing dry-run JSON is required; cells are lowered in-process
-(this script must be the FIRST jax importer in the process — it routes
-through :mod:`repro.launch.dryrun`, which sets the host-device-count
-XLA flag — so ``benchmarks/run.py`` invokes it as a subprocess).
+(the script sets the host-device-count XLA flag before JAX first touches
+a backend, so ``benchmarks/run.py`` invokes it as a subprocess).
 """
 from __future__ import annotations
 
@@ -35,14 +34,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# A smoke run needs only a tiny debug mesh; claim the flag before the
-# dryrun import pins the 512-device default.
-if "--smoke" in sys.argv:
-    os.environ.setdefault(
-        "REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-    )
-
-from repro.launch import dryrun  # noqa: E402  (must precede any jax import)
+from repro.launch import dryrun  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -235,6 +227,13 @@ def run(arch: str, seq: int, batch: int, mesh_kind: str, backends, smoke: bool,
 
 
 def main():
+    # fake host devices for the dry-run mesh, before JAX first touches a
+    # backend; a smoke run needs only a tiny debug mesh
+    if "--smoke" in sys.argv:
+        os.environ.setdefault(
+            "REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=8"
+        )
+    dryrun.force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="smoke config on a 2x2 debug mesh")

@@ -20,7 +20,8 @@ Prints ``name,us_per_call,derived`` CSV lines.  Table mapping:
 Every benchmark also writes a JSON artifact under results/ through
 ``benchmarks.common.write_json``.  ``benchmarks.roofline`` (fused vs
 composed emulated decode, dry-run derived) runs as a subprocess because
-it must set the host-device-count XLA flag before jax initializes.
+it must set the host-device-count XLA flag before jax initializes; the
+child is held to the CPU, since the parent may hold the chip.
 """
 from __future__ import annotations
 
@@ -34,7 +35,9 @@ def _roofline(fast: bool) -> None:
     cmd = [sys.executable, os.path.join(os.path.dirname(__file__), "roofline.py")]
     if fast:
         cmd.append("--smoke")
-    subprocess.run(cmd, check=True)
+    # a dry-run on fake host devices: on a TPU host this parent already
+    # holds the chip, which a child reaching for it would hang on
+    subprocess.run(cmd, check=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
 
 def main() -> None:

@@ -61,9 +61,10 @@ class ApproxCtx:
     stats against the exact matmul (see ``injection.calibrate_matmul``).
 
     ``fused`` routes MODEL-mode projections through the backend's fused
-    kernel (matmul + chip + correction in one pass — the serving decode
-    hot path) when the spec provides one; the composed sequence above is
-    the bit-exactness oracle and the automatic fallback.
+    kernel (both unipolar planes, the rescale and the cast in one pass,
+    then chip + correction on its output — the serving decode hot path)
+    when the spec provides one; the composed sequence above is the
+    bit-exactness oracle and the automatic fallback.
 
     ``site_idx`` is the one-compile heterogeneous-dispatch hook
     (:mod:`repro.core.switch`): an int32 index array over
@@ -160,8 +161,8 @@ def _approx_branch(x, w, site: str, backend, ctx: ApproxCtx, rng, gate=None):
     if cfg.mode == TrainMode.MODEL:
         spec = registry.get(backend)
         if ctx.fused and ctx.blend is None and spec.fused_emulate is not None:
-            # fused hot path: matmul + chip + correction in ONE kernel
-            # pass (one HBM round trip).  Bit-identical to the composed
+            # fused hot path: one kernel pass for the matmul, then chip
+            # + correction on its output.  Bit-identical to the composed
             # sequence below — enforced by tests/test_fused.py.
             colgain, coladd = variation.chip_epilogue(
                 site, bname, ctx.chip, w.shape[-1], compute_dtype

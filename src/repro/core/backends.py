@@ -162,10 +162,11 @@ def _emulate_log_mult(x, w, p: LogMultParams, rng):
 
 
 # ---------------------------------------------------------------------------
-# Fused MODEL-mode emulators: matmul + chip/calibration epilogue in one
-# kernel pass (the serving hot path).  Value-domain scaling mirrors the
-# composed emulators above op for op; the kernels replicate the composed
-# accumulation order, so fused == composed bit for bit.
+# Fused MODEL-mode emulators: the matmul (both unipolar planes where the
+# backend has two), rescale and cast in one kernel pass, then the
+# chip/calibration epilogue (the serving hot path).  Value-domain scaling
+# mirrors the composed emulators above op for op; the kernels replicate the
+# composed accumulation order, so fused == composed bit for bit.
 # ---------------------------------------------------------------------------
 
 

@@ -236,9 +236,10 @@ def fused_model_mode_matmul(
     x, w, cfg: ApproxConfig, rng, epi: dict, backend: Optional[Backend] = None,
     gate=None,
 ):
-    """Fused MODEL-mode projection: one kernel pass applies the emulated
-    matmul, chip gain/offset and calibration correction (``epi`` — see
-    :func:`repro.kernels.epilogue.apply_epilogue`).  Requires the
+    """Fused MODEL-mode projection: one kernel pass computes the emulated
+    matmul, then the chip gain/offset and calibration correction (``epi``
+    — see :func:`repro.kernels.epilogue.apply_epilogue`) apply to its
+    output.  Requires the
     backend's spec to provide ``fused_emulate``; callers (``dense()``)
     fall back to the composed path when it doesn't.  ``gate`` routes the
     backward through the int8 emulation (see :func:`_gated_vjp`).

@@ -66,8 +66,8 @@ class BackendSpec:
                           ``(x, w, params, rng, epi) -> y`` that applies
                           the chip/calibration epilogue ``epi`` (see
                           :func:`repro.kernels.epilogue.apply_epilogue`)
-                          in-register on the matmul accumulator — one HBM
-                          round trip instead of four.  ``None`` means "no
+                          to the output of one fused matmul kernel.
+                          ``None`` means "no
                           fused path": ``dense()`` falls back to the
                           composed emulate -> apply_chip -> correct
                           sequence, so third-party backends keep working

@@ -5,13 +5,14 @@ array-sized dot products; each array's partial sum passes through a
 low-bit ADC (clamp to the ADC range + round to 2^bits levels) before
 digital accumulation (paper Sec. 2.2 / 3).
 
-TPU mapping (DESIGN.md Sec. 3): this is a K-blocked matmul whose K-block
-equals the analog array size.  Each (i, j, k) grid step computes one
-MXU-shaped (bm x bn) tile of one array's partial sum in VMEM, applies the
-fake-ADC pointwise quantizer on the VPU, and accumulates into the output
-block, which stays resident in VMEM across the (sequential, innermost) k
-dimension.  With ``array_size = 128`` the contraction dim is exactly one
-MXU pass per array.
+TPU mapping: this is a K-blocked matmul whose K-block equals the analog
+array size.  Each (i, j, k) grid step computes one MXU-shaped (bm x bn)
+tile of one array's partial sum in VMEM, applies the fake-ADC pointwise
+quantizer on the VPU, and accumulates into a VMEM accumulator that stays
+resident across the (sequential, innermost) k dimension.  With
+``array_size = 128`` the contraction dim is exactly one MXU pass per
+array.  The fused variant accumulates both unipolar weight planes in the
+same grid, tile for tile, so its dots are the unfused kernel's dots.
 """
 from __future__ import annotations
 
@@ -20,16 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.epilogue import apply_epilogue
-from repro.kernels.vpu_matmul import _row_operand
-
-try:  # scratch memory spaces are TPU-specific; interpret mode accepts them
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _SCRATCH = None
+from repro.kernels.vpu_matmul import tile
 
 
 def _adc_quantize(psum, adc_bits: int, adc_range: float):
@@ -39,24 +33,76 @@ def _adc_quantize(psum, adc_bits: int, adc_range: float):
     # The trailing minimum is a semantic no-op (q <= adc_range up to one
     # rounding) whose real job is keeping the final op a non-multiply:
     # XLA CPU contracts a multiply feeding an add/sub into an FMA, which
-    # would make the SAME quantizer round differently inside the fused
-    # kernel (where a subtraction consumes it in-register) than in this
-    # unfused kernel (where a store does) — breaking fused-vs-composed
-    # bit-exactness by an ulp.
+    # would let the same quantizer round differently depending on what
+    # consumes it — breaking fused-vs-composed bit-exactness by an ulp.
     return jnp.minimum(q, adc_range)
 
 
-def _kernel(x_ref, w_ref, o_ref, *, adc_bits: int, adc_range: float):
+def _kernel(x_ref, *refs, n_w: int, has_pre: bool, adc_bits: int,
+            adc_range: float):
+    w_refs = refs[:n_w]
+    pre_ref = refs[n_w] if has_pre else None
+    o_ref = refs[n_w + has_pre]
+    acc_refs = refs[n_w + has_pre + 1:]
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        for acc in acc_refs:
+            acc[...] = jnp.zeros_like(acc)
 
-    psum = jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
-    )  # one analog array's raw partial sum for this (bm, bn) tile
-    o_ref[...] += _adc_quantize(psum, adc_bits, adc_range)
+    x = x_ref[...]
+    for acc, w in zip(acc_refs, w_refs):
+        # one analog array's raw partial sum for this (bm, bn) tile
+        psum = jnp.dot(x, w[...], preferred_element_type=jnp.float32)
+        acc[...] += _adc_quantize(psum, adc_bits, adc_range)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _finish():
+        # the two planes accumulate independently and subtract once at
+        # the end — Sum(adc_p) - Sum(adc_n), matching the composed
+        # split_unipolar_contract order, not Sum(adc_p - adc_n)
+        y = acc_refs[0][...]
+        if n_w == 2:
+            y = y - acc_refs[1][...]
+        if has_pre:
+            y = (y * pre_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = y
+
+
+def _analog_call(x, ws, array_size, adc_bits, adc_range, prescale, out_dtype,
+                 block_m, block_n, interpret):
+    M, K = x.shape
+    N = ws[0].shape[1]
+    bm, Mp = tile(M, block_m, align=8)
+    bn, Np = tile(N, block_n)
+    Kp = -(-K // array_size) * array_size
+    operands = [jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))]
+    operands += [
+        jnp.pad(w.astype(jnp.float32), ((0, Kp - K), (0, Np - N))) for w in ws
+    ]
+    in_specs = [pl.BlockSpec((bm, array_size), lambda i, j, k: (i, k))]
+    in_specs += [pl.BlockSpec((array_size, bn), lambda i, j, k: (k, j))] * len(ws)
+    has_pre = prescale is not None
+    if has_pre:
+        operands.append(jnp.asarray(prescale, jnp.float32).reshape(1, 1))
+        in_specs.append(pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel, n_w=len(ws), has_pre=has_pre,
+            adc_bits=adc_bits, adc_range=adc_range,
+        ),
+        grid=(Mp // bm, Np // bn, Kp // array_size),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(
+            (Mp, Np), out_dtype if has_pre else jnp.float32
+        ),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32) for _ in ws],
+        interpret=interpret,
+    )(*operands)
+    return out[:M, :N]
 
 
 def analog_matmul(
@@ -67,102 +113,14 @@ def analog_matmul(
     adc_range: float,
     *,
     block_m: int = 128,
-    block_n: int = 128,
+    block_n: int = 512,
     interpret: bool = False,
 ):
     """x: [M, K] unipolar float32, w: [K, N] unipolar float32 -> [M, N]."""
-    M, K = x.shape
-    _, N = w.shape
-    pad_m = (-M) % block_m
-    pad_n = (-N) % block_n
-    pad_k = (-K) % array_size
-    if pad_m or pad_k:
-        x = jnp.pad(x, ((0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        w = jnp.pad(w, ((0, pad_k), (0, pad_n)))
-    Mp, Kp = x.shape
-    Np = w.shape[1]
-    grid = (Mp // block_m, Np // block_n, Kp // array_size)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, adc_bits=adc_bits, adc_range=adc_range),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, array_size), lambda i, j, k: (i, k)),
-            pl.BlockSpec((array_size, block_n), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        interpret=interpret,
-    )(x.astype(jnp.float32), w.astype(jnp.float32))
-    return out[:M, :N]
-
-
-# ---------------------------------------------------------------------------
-# Fused variant: both unipolar planes + MODEL-mode epilogue in one kernel
-# ---------------------------------------------------------------------------
-
-
-def _fused_kernel(
-    *refs,
-    adc_bits: int,
-    adc_range: float,
-    block_n: int,
-    has_gain: bool,
-    has_add: bool,
-    has_corr: bool,
-    out_dtype,
-):
-    it = iter(refs)
-    x_ref = next(it)
-    wp_ref = next(it)
-    wn_ref = next(it)
-    pre_ref = next(it)
-    gain_ref = next(it) if has_gain else None
-    add_ref = next(it) if has_add else None
-    coeff_ref = next(it) if has_corr else None
-    cscale_ref = next(it) if has_corr else None
-    o_ref = next(it)
-    acc_p_ref = next(it)
-    acc_n_ref = next(it)
-
-    k = pl.program_id(1)
-    nk = pl.num_programs(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_p_ref[...] = jnp.zeros_like(acc_p_ref)
-        acc_n_ref[...] = jnp.zeros_like(acc_n_ref)
-
-    x = x_ref[...]  # [bm, array_size] f32
-    wp = wp_ref[...]  # [array_size, Np] f32
-    wn = wn_ref[...]
-    # chunk N so each dot has the unfused kernel's exact (bm x bn) shape:
-    # same dot, same values -> same bits
-    parts_p, parts_n = [], []
-    for c in range(wp.shape[1] // block_n):
-        sl = slice(c * block_n, (c + 1) * block_n)
-        psum_p = jnp.dot(x, wp[:, sl], preferred_element_type=jnp.float32)
-        psum_n = jnp.dot(x, wn[:, sl], preferred_element_type=jnp.float32)
-        parts_p.append(_adc_quantize(psum_p, adc_bits, adc_range))
-        parts_n.append(_adc_quantize(psum_n, adc_bits, adc_range))
-    acc_p_ref[...] += jnp.concatenate(parts_p, axis=1)
-    acc_n_ref[...] += jnp.concatenate(parts_n, axis=1)
-
-    @pl.when(k == nk - 1)
-    def _finish():
-        # the two planes accumulate independently and subtract once at the
-        # end — Sum(adc_p) - Sum(adc_n), matching the composed
-        # split_unipolar_contract order, not Sum(adc_p - adc_n)
-        y = ((acc_p_ref[...] - acc_n_ref[...]) * pre_ref[...]).astype(out_dtype)
-        y = apply_epilogue(
-            y,
-            colgain=gain_ref[...] if has_gain else None,
-            coladd=add_ref[...] if has_add else None,
-            mean_coeffs=coeff_ref[...] if has_corr else None,
-            mean_scale=cscale_ref[0, 0] if has_corr else None,
-        )
-        o_ref[...] = y
+    return _analog_call(
+        x, [w], array_size, adc_bits, adc_range, None, jnp.float32,
+        block_m, block_n, interpret,
+    )
 
 
 def analog_matmul_fused(
@@ -173,82 +131,17 @@ def analog_matmul_fused(
     adc_bits: int,
     adc_range: float,
     prescale,
-    epi: dict,
     out_dtype,
     *,
     block_m: int = 128,
-    block_n: int = 128,
+    block_n: int = 512,
     interpret: bool = False,
 ):
     """Fused dual-plane analog matmul: ``x @ w_pos - x @ w_neg`` with ADC
-    partial-sum quantization per array, the scalar rescale, and the
-    chip/calibration epilogue applied before the single writeback.
-
-    ``prescale`` is the composed path's scalar ``sx * sw``.
-    """
-    M, K = x.shape
-    N = w_pos.shape[1]
-    pad_m = (-M) % block_m
-    pad_n = (-N) % block_n
-    pad_k = (-K) % array_size
-    if pad_m or pad_k:
-        x = jnp.pad(x, ((0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        w_pos = jnp.pad(w_pos, ((0, pad_k), (0, pad_n)))
-        w_neg = jnp.pad(w_neg, ((0, pad_k), (0, pad_n)))
-    Mp, Kp = x.shape
-    Np = w_pos.shape[1]
-    grid = (Mp // block_m, Kp // array_size)
-
-    colgain = epi.get("colgain")
-    coladd = epi.get("coladd")
-    coeffs = epi.get("mean_coeffs")
-    cscale = epi.get("mean_scale")
-
-    operands = [
-        x.astype(jnp.float32),
-        w_pos.astype(jnp.float32),
-        w_neg.astype(jnp.float32),
-        jnp.asarray(prescale).reshape(1, 1),
-    ]
-    in_specs = [
-        pl.BlockSpec((block_m, array_size), lambda i, k: (i, k)),
-        pl.BlockSpec((array_size, Np), lambda i, k: (k, 0)),
-        pl.BlockSpec((array_size, Np), lambda i, k: (k, 0)),
-        pl.BlockSpec((1, 1), lambda i, k: (0, 0)),
-    ]
-    if colgain is not None:
-        operands.append(_row_operand(colgain, Np, out_dtype))
-        in_specs.append(pl.BlockSpec((1, Np), lambda i, k: (0, 0)))
-    if coladd is not None:
-        operands.append(_row_operand(coladd, Np, out_dtype))
-        in_specs.append(pl.BlockSpec((1, Np), lambda i, k: (0, 0)))
-    if coeffs is not None:
-        P = coeffs.shape[-1]
-        operands.append(jnp.asarray(coeffs, jnp.float32).reshape(1, P))
-        in_specs.append(pl.BlockSpec((1, P), lambda i, k: (0, 0)))
-        operands.append(jnp.asarray(cscale, jnp.float32).reshape(1, 1))
-        in_specs.append(pl.BlockSpec((1, 1), lambda i, k: (0, 0)))
-
-    out = pl.pallas_call(
-        functools.partial(
-            _fused_kernel,
-            adc_bits=adc_bits,
-            adc_range=adc_range,
-            block_n=block_n,
-            has_gain=colgain is not None,
-            has_add=coladd is not None,
-            has_corr=coeffs is not None,
-            out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, Np), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=[
-            _SCRATCH((block_m, Np), jnp.float32),
-            _SCRATCH((block_m, Np), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return out[:M, :N]
+    partial-sum quantization per array, then the scalar rescale
+    ``prescale`` (the composed path's ``sx * sw``) and the cast to
+    ``out_dtype`` before the single writeback."""
+    return _analog_call(
+        x, [w_pos, w_neg], array_size, adc_bits, adc_range, prescale,
+        out_dtype, block_m, block_n, interpret,
+    )

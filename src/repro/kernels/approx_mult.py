@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.vpu_matmul import elementwise_matmul, elementwise_matmul_fused
+from repro.kernels.vpu_matmul import elementwise_matmul
 
 
 def _approx_mul(a, b, drop_scale: float):
@@ -27,39 +27,18 @@ def approx_mult_matmul(
     mult_bits: int,
     perforate: int,
     *,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 128,
+    prescale=None,
+    out_dtype=jnp.float32,
     interpret: bool = False,
+    **blocks,
 ):
-    """x: [M, K] integer-valued floats in [-(2^b-1), 2^b-1], w: [K, N]."""
+    """x: [M, K] integer-valued floats in [-(2^b-1), 2^b-1], w: [K, N].
+
+    With ``prescale`` ([M, 1]) the accumulator is rescaled and cast to
+    ``out_dtype`` in the kernel (the fused MODEL-mode entry point)."""
     del mult_bits
     drop_scale = float(1 << (2 * perforate))
     return elementwise_matmul(
         x, w, lambda a, b: _approx_mul(a, b, drop_scale),
-        block_m=block_m, block_n=block_n, block_k=block_k, interpret=interpret,
-    )
-
-
-def approx_mult_matmul_fused(
-    x,
-    w,
-    mult_bits: int,
-    perforate: int,
-    prescale,
-    epi: dict,
-    out_dtype,
-    *,
-    block_m: int = 128,
-    block_k: int = 128,
-    interpret: bool = False,
-):
-    """Fused variant: truncated-product matmul with the per-token rescale
-    and chip/calibration epilogue applied in-register before writeback."""
-    del mult_bits
-    drop_scale = float(1 << (2 * perforate))
-    return elementwise_matmul_fused(
-        x, w, lambda a, b: _approx_mul(a, b, drop_scale),
-        prescale, epi, out_dtype,
-        block_m=block_m, block_k=block_k, interpret=interpret,
+        prescale=prescale, out_dtype=out_dtype, interpret=interpret, **blocks,
     )

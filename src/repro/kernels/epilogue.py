@@ -3,10 +3,11 @@
 The unfused MODEL path applies three separate XLA ops after the backend
 matmul: ``variation.apply_chip`` (per-column gain/offset or fault error,
 scaled by the per-token row max), then an optional calibration
-correction subtract (``y - predict_mean(stats, y)``).  The fused Pallas
-kernels apply the identical math in-register on the accumulator tile
-before writeback; this module holds the single definition both sides
-share so bit-exactness is a property of the code, not a test fixture.
+correction subtract (``y - predict_mean(stats, y)``).  The fused path
+(:mod:`repro.kernels.ops`) applies the identical math to the output of
+its one fused matmul kernel; this module holds the single definition
+both sides share so bit-exactness is a property of the code, not a test
+fixture.
 
 Two invariants matter for exactness:
 
@@ -15,8 +16,8 @@ Two invariants matter for exactness:
   summation order XLA is free to rearrange between the fused and
   composed graphs.
 * the per-token row scale is ``max(max|y|, eps)`` — a pure max chain,
-  order-independent, so computing it on a full row inside the kernel or
-  outside on the assembled output yields the same bits.
+  order-independent, so taking it in a separate pass over the output of
+  an N-tiled kernel yields the same bits as over a whole row.
 """
 from __future__ import annotations
 

@@ -12,6 +12,11 @@ Continuous batching makes the cache ragged: every slot sits at its own
 (bucketing — the @pl.when guard below), and the straddling block masks
 per-element with the same NEG_INF the jnp path uses.
 
+TPU layout: the cache is viewed as ``[B, S, KV * dh]`` (a free reshape),
+so every K/V block is a ``(bs, dh)`` tile of one KV head; per-row
+positions arrive by scalar prefetch in SMEM, where both the kernel and
+the block index maps can read them.
+
 Equivalence to ``models.layers.decode_attention`` is allclose, not
 bitwise: online softmax reassociates the normalizer sum.
 """
@@ -23,18 +28,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # scratch memory spaces are TPU-specific; interpret mode accepts them
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _SCRATCH = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _kernel(
-    q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, l_ref, m_ref, *, block_s: int
+    pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, l_ref, m_ref, *, block_s: int
 ):
     s = pl.program_id(2)
     ns = pl.num_programs(2)
@@ -45,16 +45,17 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
     start = s * block_s
 
-    # bucketed skip: blocks wholly past this row's position never load
+    # bucketed skip: blocks wholly past this row's position are neither
+    # computed here nor (see kv_block in flash_decode) copied in
     @pl.when(start <= pos)
     def _compute():
         dh = q_ref.shape[-1]
         q = q_ref[0, 0].astype(jnp.float32)  # [G, dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bs, dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # [bs, dh]
+        k = k_ref[0].astype(jnp.float32)  # [bs, dh]
+        v = v_ref[0].astype(jnp.float32)  # [bs, dh]
         logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
             dh ** -0.5
         )  # [G, bs]
@@ -92,28 +93,41 @@ def flash_decode(q, cache_k, cache_v, pos_vec, *, interpret: bool = False):
     B, KV, G, dh = q.shape
     S = cache_k.shape[1]
     bs = _block_s(S)
-    grid = (B, KV, S // bs)
-    pos2d = jnp.asarray(pos_vec, jnp.int32).reshape(B, 1)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_s=bs),
-        grid=grid,
+    def kv_block(b, kv, s, pos_ref):
+        # a block wholly past the row's position maps to the row's last
+        # live block: the pipeline skips the copy of a block it holds
+        return (b, jnp.minimum(s, pos_ref[b] // bs), kv)
+
+    def q_block(b, kv, s, pos_ref):
+        return (b, kv, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, KV, S // bs),
         in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda b, kv, s: (b, kv, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dh), lambda b, kv, s: (b, s, kv, 0)),
-            pl.BlockSpec((1, bs, 1, dh), lambda b, kv, s: (b, s, kv, 0)),
-            pl.BlockSpec((1, 1), lambda b, kv, s: (b, 0)),
+            pl.BlockSpec((1, 1, G, dh), q_block),
+            pl.BlockSpec((1, bs, dh), kv_block),
+            pl.BlockSpec((1, bs, dh), kv_block),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, dh), lambda b, kv, s: (b, kv, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, dh), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, G, dh), q_block),
         scratch_shapes=[
-            _SCRATCH((G, dh), jnp.float32),
-            _SCRATCH((G, 1), jnp.float32),
-            _SCRATCH((G, 1), jnp.float32),
+            pltpu.VMEM((G, dh), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, block_s=bs),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, dh), jnp.float32),
         interpret=interpret,
-    )(q, cache_k, cache_v, pos2d)
-    return out
+    )(
+        jnp.asarray(pos_vec, jnp.int32).reshape(B),
+        q,
+        cache_k.reshape(B, S, KV * dh),
+        cache_v.reshape(B, S, KV * dh),
+    )
 
 
 def flash_decode_ref(q, cache_k, cache_v, pos_vec):

@@ -9,40 +9,26 @@ the math — only on the blocking/accumulation, which is what it's for.
 """
 from __future__ import annotations
 
+import jax.numpy as jnp
+
 from repro.kernels import ref
-from repro.kernels.vpu_matmul import elementwise_matmul, elementwise_matmul_fused
+from repro.kernels.vpu_matmul import elementwise_matmul
 
 
 def log_matmul(
     x,
     w,
     *,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 128,
+    prescale=None,
+    out_dtype=jnp.float32,
     interpret: bool = False,
+    **blocks,
 ):
-    """x: [M, K] integer-valued floats, w: [K, N] likewise -> [M, N] f32."""
+    """x: [M, K] integer-valued floats, w: [K, N] likewise -> [M, N] f32.
+
+    With ``prescale`` ([M, 1]) the accumulator is rescaled and cast to
+    ``out_dtype`` in the kernel (the fused MODEL-mode entry point)."""
     return elementwise_matmul(
         x, w, ref.mitchell_mul,
-        block_m=block_m, block_n=block_n, block_k=block_k, interpret=interpret,
-    )
-
-
-def log_matmul_fused(
-    x,
-    w,
-    prescale,
-    epi: dict,
-    out_dtype,
-    *,
-    block_m: int = 128,
-    block_k: int = 128,
-    interpret: bool = False,
-):
-    """Fused variant: Mitchell-multiplier matmul with the per-token rescale
-    and chip/calibration epilogue applied in-register before writeback."""
-    return elementwise_matmul_fused(
-        x, w, ref.mitchell_mul, prescale, epi, out_dtype,
-        block_m=block_m, block_k=block_k, interpret=interpret,
+        prescale=prescale, out_dtype=out_dtype, interpret=interpret, **blocks,
     )

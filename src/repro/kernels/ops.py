@@ -81,30 +81,38 @@ def log_matmul(x, w):
     return kref.log_matmul_ref(x.astype(jnp.float32), w.astype(jnp.float32))
 
 
-def sc_matmul(xp, wp, n_bits: int, rng_x, rng_w):
-    """Probability-domain [M,K] @ [K,N] through packed SC streams.
-
-    Stream generation (threshold vs shared per-port generator sequences)
-    happens here so the Pallas kernel and the reference consume identical
-    packed words and can be compared bit-exactly.
-    """
-    if _impl() != "pallas":
-        return kref.sc_matmul_ref(xp, wp, n_bits, rng_x, rng_w)
-    K = xp.shape[-1]
-    # shared activation-side generator / per-row weight generators —
-    # must match ref.sc_matmul_ref exactly (bit-exact kernel validation)
+def _sc_streams(x, ws, n_bits: int, rng_x, rng_w):
+    """Pack SC streams for an activation plane [M, K] and weight planes
+    [K, N]: one activation-side generator sequence shared by all K ports,
+    an independent generator per weight row — the draws
+    ``ref.sc_matmul_ref`` makes, so the kernel and the oracle consume
+    identical packed words."""
+    K = x.shape[-1]
     ux = jnp.broadcast_to(
         jax.random.uniform(rng_x, (1, n_bits), dtype=jnp.float32), (K, n_bits)
     )
     uw = jax.random.uniform(rng_w, (K, n_bits), dtype=jnp.float32)
-    xbits = kref.sc_pack_streams(xp.astype(jnp.float32), ux)
-    wbits = kref.sc_pack_streams(wp.astype(jnp.float32), uw[:, None, :])
-    counts = _sc.sc_matmul_packed(xbits, wbits, n_bits, interpret=_interpret())
-    return counts
+    xbits = kref.sc_pack_streams(x.astype(jnp.float32), ux)
+    return xbits, [
+        kref.sc_pack_streams(w.astype(jnp.float32), uw[:, None, :]) for w in ws
+    ]
+
+
+def sc_matmul(xp, wp, n_bits: int, rng_x, rng_w):
+    """Probability-domain [M,K] @ [K,N] through packed SC streams."""
+    if _impl() != "pallas":
+        return kref.sc_matmul_ref(xp, wp, n_bits, rng_x, rng_w)
+    xbits, (wbits,) = _sc_streams(xp, [wp], n_bits, rng_x, rng_w)
+    return _sc.sc_matmul_packed(xbits, wbits, n_bits, interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------
-# Fused dispatch: matmul + MODEL-mode epilogue in one pass
+# Fused dispatch: one kernel pass for the matmul (both unipolar planes where
+# the backend has two), the per-token rescale and the cast; the chip +
+# calibration epilogue then runs on the [M, N] result.  The epilogue's row
+# scale is a max over the whole output row, which an N-tiled kernel does not
+# see; a max chain is order-free, so taking it in a separate pass over the
+# written tile gives the composed path's bits.
 # ---------------------------------------------------------------------------
 
 
@@ -112,80 +120,86 @@ def analog_matmul_fused(
     x, w_pos, w_neg, array_size: int, adc_bits: int, adc_range: float,
     prescale, epi: dict, out_dtype,
 ):
-    """Dual-plane unipolar contraction with ADC quantization, rescale and
-    chip/calibration epilogue fused into the writeback."""
+    """Dual-plane unipolar contraction with ADC quantization and rescale in
+    one kernel, then the chip/calibration epilogue."""
     if _impl() == "pallas":
-        return _analog.analog_matmul_fused(
+        y = _analog.analog_matmul_fused(
             x, w_pos, w_neg, array_size, adc_bits, adc_range,
-            prescale, epi, out_dtype, interpret=_interpret(),
+            prescale, out_dtype, interpret=_interpret(),
         )
-    xf = x.astype(jnp.float32)
-    out = kref.analog_matmul_ref(
-        xf, w_pos.astype(jnp.float32), array_size, adc_bits, adc_range
-    ) - kref.analog_matmul_ref(
-        xf, w_neg.astype(jnp.float32), array_size, adc_bits, adc_range
-    )
-    return apply_epilogue((out * prescale).astype(out_dtype), **epi)
+    else:
+        xf = x.astype(jnp.float32)
+        out = kref.analog_matmul_ref(
+            xf, w_pos.astype(jnp.float32), array_size, adc_bits, adc_range
+        ) - kref.analog_matmul_ref(
+            xf, w_neg.astype(jnp.float32), array_size, adc_bits, adc_range
+        )
+        y = (out * prescale).astype(out_dtype)
+    return apply_epilogue(y, **epi)
 
 
 def approx_mult_matmul_fused(
     x, w, mult_bits: int, perforate: int, prescale, epi: dict, out_dtype
 ):
-    """Approximate-multiplier contraction with the fused epilogue."""
+    """Approximate-multiplier contraction and rescale in one kernel, then
+    the chip/calibration epilogue."""
     if _impl() == "pallas":
-        return _amult.approx_mult_matmul_fused(
-            x, w, mult_bits, perforate, prescale, epi, out_dtype,
-            interpret=_interpret(),
+        y = _amult.approx_mult_matmul(
+            x, w, mult_bits, perforate, prescale=prescale,
+            out_dtype=out_dtype, interpret=_interpret(),
         )
-    del mult_bits
-    drop_bits = 2 * perforate
-    acc = kref.elementwise_matmul_chunked_ref(
-        x.astype(jnp.float32), w.astype(jnp.float32),
-        lambda a, b: kref.approx_mul(a, b, drop_bits),
-    )
-    return apply_epilogue((acc * prescale).astype(out_dtype), **epi)
+    else:
+        drop_bits = 2 * perforate
+        acc = kref.elementwise_matmul_chunked_ref(
+            x.astype(jnp.float32), w.astype(jnp.float32),
+            lambda a, b: kref.approx_mul(a, b, drop_bits),
+        )
+        y = (acc * prescale).astype(out_dtype)
+    return apply_epilogue(y, **epi)
 
 
 def log_matmul_fused(x, w, prescale, epi: dict, out_dtype):
-    """Mitchell-multiplier contraction with the fused epilogue."""
+    """Mitchell-multiplier contraction and rescale in one kernel, then the
+    chip/calibration epilogue."""
     if _impl() == "pallas":
-        return _log.log_matmul_fused(
-            x, w, prescale, epi, out_dtype, interpret=_interpret()
+        y = _log.log_matmul(
+            x, w, prescale=prescale, out_dtype=out_dtype,
+            interpret=_interpret(),
         )
-    acc = kref.elementwise_matmul_chunked_ref(
-        x.astype(jnp.float32), w.astype(jnp.float32), kref.mitchell_mul
-    )
-    return apply_epilogue((acc * prescale).astype(out_dtype), **epi)
+    else:
+        acc = kref.elementwise_matmul_chunked_ref(
+            x.astype(jnp.float32), w.astype(jnp.float32), kref.mitchell_mul
+        )
+        y = (acc * prescale).astype(out_dtype)
+    return apply_epilogue(y, **epi)
 
 
 def sc_matmul_fused(
     xcat, w_pos, w_neg, n_bits: int, rng_x, rng_w, prescale, epi: dict, out_dtype
 ):
-    """Dual-plane SC stream contraction with the fused epilogue.
+    """Dual-plane SC stream contraction and rescale in one kernel, then the
+    chip/calibration epilogue.
 
     ``xcat``/``w_pos``/``w_neg`` are the concatenated probability planes
     from ``split_unipolar_contract``'s layout; stream generation matches
     the unfused :func:`sc_matmul` draws exactly (same keys, same shapes),
     so the packed words are identical bit for bit.
     """
-    K = xcat.shape[-1]
-    ux = jnp.broadcast_to(
-        jax.random.uniform(rng_x, (1, n_bits), dtype=jnp.float32), (K, n_bits)
+    xbits, (wp_bits, wn_bits) = _sc_streams(
+        xcat, [w_pos, w_neg], n_bits, rng_x, rng_w
     )
-    uw = jax.random.uniform(rng_w, (K, n_bits), dtype=jnp.float32)
-    xbits = kref.sc_pack_streams(xcat.astype(jnp.float32), ux)
-    wp_bits = kref.sc_pack_streams(w_pos.astype(jnp.float32), uw[:, None, :])
-    wn_bits = kref.sc_pack_streams(w_neg.astype(jnp.float32), uw[:, None, :])
     if _impl() == "pallas":
-        return _sc.sc_matmul_packed_fused(
-            xbits, wp_bits, wn_bits, n_bits, prescale, epi, out_dtype,
+        y = _sc.sc_matmul_packed_fused(
+            xbits, wp_bits, wn_bits, n_bits, prescale, out_dtype,
             interpret=_interpret(),
         )
-    r = (
-        kref.sc_matmul_packed_chunked_ref(xbits, wp_bits) / n_bits
-        - kref.sc_matmul_packed_chunked_ref(xbits, wn_bits) / n_bits
-    )
-    return apply_epilogue((r * prescale).astype(out_dtype), **epi)
+    else:
+        r = (
+            kref.sc_matmul_packed_chunked_ref(xbits, wp_bits) / n_bits
+            - kref.sc_matmul_packed_chunked_ref(xbits, wn_bits) / n_bits
+        )
+        y = (r * prescale).astype(out_dtype)
+    return apply_epilogue(y, **epi)
 
 
 def flash_decode_attention(q, cache_k, cache_v, pos_vec):

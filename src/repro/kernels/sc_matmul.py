@@ -5,251 +5,64 @@ single AND gate, accumulate is an OR tree (paper Sec. 2.1, setup of [17]).
 Emulating this is the expensive MODEL-mode forward (Tab. 1: 64x unrolled /
 2x packed per op).
 
-TPU mapping (DESIGN.md Sec. 3): the GPU/CPU version bit-twiddles LFSRs
-serially; on TPU we instead (a) generate streams *outside* the kernel by
-threshold-comparing values against shared per-port generator sequences,
-(b) pack them into uint32 lanes, and (c) contract with a VPU kernel:
-AND the packed words, OR-accumulate over K into a VMEM scratch
-accumulator, popcount once per output tile on the last K step.
+TPU mapping: the GPU/CPU version bit-twiddles LFSRs serially; on TPU we
+instead (a) generate streams *outside* the kernel by threshold-comparing
+values against shared per-port generator sequences, (b) pack them into
+uint32 words, and (c) contract with the shared VPU scaffolding
+(:func:`repro.kernels.vpu_matmul.contract`): AND the packed words,
+OR-accumulate over K into a VMEM scratch accumulator, popcount once per
+output tile on the last K step.  The stream-word axis ``W`` is the
+leading plane axis of every block, not the lane axis, so a 32-bit stream
+costs one word per value in VMEM rather than a 128-lane tile.
 
-The packed-word layout matches ``ref.sc_matmul_packed_ref`` bit-for-bit,
+The packed-word values match ``ref.sc_matmul_packed_ref`` bit-for-bit,
 so the kernel is validated bit-exactly against the oracle.
 """
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.epilogue import apply_epilogue
-from repro.kernels.vpu_matmul import _row_operand
-
-try:  # scratch memory spaces are TPU-specific; interpret mode accepts them
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _SCRATCH = None
+from repro.kernels.vpu_matmul import contract, popcount_value
 
 
-def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_bits: int, block_k: int):
-    k = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]  # [bm, bk, W] uint32 packed streams
-    w = w_ref[...]  # [bk, bn, W] uint32 packed streams
-
-    def body(i, acc):
-        # AND = stream multiply; OR = stream accumulate
-        prod = jnp.bitwise_and(x[:, i, None, :], w[None, i, :, :])
-        return jnp.bitwise_or(acc, prod)
-
-    acc_ref[...] = jax.lax.fori_loop(0, block_k, body, acc_ref[...])
-
-    @pl.when(k == nk - 1)
-    def _finish():
-        counts = jax.lax.population_count(acc_ref[...])
-        o_ref[...] = counts.astype(jnp.float32).sum(-1) / n_bits
+def _planes(bits):
+    """[..., W] packed words -> [W, ...] word planes."""
+    return jnp.moveaxis(bits, -1, 0)
 
 
-def sc_matmul_packed(
-    xbits,
-    wbits,
-    n_bits: int,
-    *,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 128,
-    interpret: bool = False,
-):
+def _dual_value(acc_p, acc_n, n_bits: int):
+    # each plane's popcount divides by n_bits independently before the
+    # subtract, exactly like the two composed kernel calls
+    return popcount_value(acc_p, n_bits) - popcount_value(acc_n, n_bits)
+
+
+def sc_matmul_packed(xbits, wbits, n_bits: int, *, interpret: bool = False,
+                     **blocks):
     """xbits: [M, K, W] uint32, wbits: [K, N, W] uint32 -> [M, N] float32
     stream value (popcount / n_bits) of the OR-accumulated AND products."""
-    M, K, W = xbits.shape
-    N = wbits.shape[1]
-    block_m = min(block_m, M) or 1
-    block_n = min(block_n, N) or 1
-    block_k = min(block_k, K) or 1
-    pad_m = (-M) % block_m
-    pad_n = (-N) % block_n
-    pad_k = (-K) % block_k
-    if pad_m or pad_k:
-        xbits = jnp.pad(xbits, ((0, pad_m), (0, pad_k), (0, 0)))
-    if pad_k or pad_n:
-        wbits = jnp.pad(wbits, ((0, pad_k), (0, pad_n), (0, 0)))
-    Mp, Kp, _ = xbits.shape
-    Np = wbits.shape[1]
-    grid = (Mp // block_m, Np // block_n, Kp // block_k)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, n_bits=n_bits, block_k=block_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k, W), lambda i, j, k: (i, k, 0)),
-            pl.BlockSpec((block_k, block_n, W), lambda i, j, k: (k, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        scratch_shapes=[_SCRATCH((block_m, block_n, W), jnp.uint32)],
-        interpret=interpret,
-    )(xbits, wbits)
-    return out[:M, :N]
-
-
-# ---------------------------------------------------------------------------
-# Fused variant: both unipolar planes + MODEL-mode epilogue in one kernel
-# ---------------------------------------------------------------------------
-
-
-def _fused_kernel(
-    *refs,
-    n_bits: int,
-    block_k: int,
-    has_gain: bool,
-    has_add: bool,
-    has_corr: bool,
-    out_dtype,
-):
-    it = iter(refs)
-    x_ref = next(it)
-    wp_ref = next(it)
-    wn_ref = next(it)
-    pre_ref = next(it)
-    gain_ref = next(it) if has_gain else None
-    add_ref = next(it) if has_add else None
-    coeff_ref = next(it) if has_corr else None
-    cscale_ref = next(it) if has_corr else None
-    o_ref = next(it)
-    acc_p_ref = next(it)
-    acc_n_ref = next(it)
-
-    k = pl.program_id(1)
-    nk = pl.num_programs(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_p_ref[...] = jnp.zeros_like(acc_p_ref)
-        acc_n_ref[...] = jnp.zeros_like(acc_n_ref)
-
-    x = x_ref[...]  # [bm, bk, W] uint32 packed streams
-    wp = wp_ref[...]  # [bk, N, W] uint32 packed streams
-    wn = wn_ref[...]
-
-    def body(i, accs):
-        acc_p, acc_n = accs
-        xw = x[:, i, None, :]
-        acc_p = jnp.bitwise_or(acc_p, jnp.bitwise_and(xw, wp[None, i, :, :]))
-        acc_n = jnp.bitwise_or(acc_n, jnp.bitwise_and(xw, wn[None, i, :, :]))
-        return acc_p, acc_n
-
-    acc_p, acc_n = jax.lax.fori_loop(
-        0, block_k, body, (acc_p_ref[...], acc_n_ref[...])
+    return contract(
+        _planes(xbits), [_planes(wbits)],
+        mul=jnp.bitwise_and, combine=jnp.bitwise_or,
+        finish=functools.partial(popcount_value, n_bits=n_bits),
+        interpret=interpret, **blocks,
     )
-    acc_p_ref[...] = acc_p
-    acc_n_ref[...] = acc_n
-
-    @pl.when(k == nk - 1)
-    def _finish():
-        # each plane's popcount divides by n_bits independently before the
-        # subtract, exactly like the two composed kernel calls
-        r_p = jax.lax.population_count(acc_p_ref[...]).astype(jnp.float32)
-        r_n = jax.lax.population_count(acc_n_ref[...]).astype(jnp.float32)
-        r = r_p.sum(-1) / n_bits - r_n.sum(-1) / n_bits
-        y = (r * pre_ref[...]).astype(out_dtype)
-        y = apply_epilogue(
-            y,
-            colgain=gain_ref[...] if has_gain else None,
-            coladd=add_ref[...] if has_add else None,
-            mean_coeffs=coeff_ref[...] if has_corr else None,
-            mean_scale=cscale_ref[0, 0] if has_corr else None,
-        )
-        o_ref[...] = y
 
 
 def sc_matmul_packed_fused(
-    xbits,
-    wp_bits,
-    wn_bits,
-    n_bits: int,
-    prescale,
-    epi: dict,
-    out_dtype,
-    *,
-    block_m: int = 128,
-    block_k: int = 128,
-    interpret: bool = False,
+    xbits, wp_bits, wn_bits, n_bits: int, prescale, out_dtype, *,
+    interpret: bool = False, **blocks,
 ):
     """Fused dual-plane SC contraction: the positive and negative stream
     planes OR-accumulate in parallel scratch, popcount once, subtract, and
-    the scalar rescale + chip/calibration epilogue run in-register before
-    the single writeback.
-
-    ``prescale`` is the composed path's scalar ``(sx * sw) / gain^2``.
-    """
-    M, K, W = xbits.shape
-    N = wp_bits.shape[1]
-    block_m = min(block_m, M) or 1
-    block_k = min(block_k, K) or 1
-    pad_m = (-M) % block_m
-    pad_n = (-N) % 128 if N > 128 else 0
-    pad_k = (-K) % block_k
-    if pad_m or pad_k:
-        xbits = jnp.pad(xbits, ((0, pad_m), (0, pad_k), (0, 0)))
-    if pad_k or pad_n:
-        wp_bits = jnp.pad(wp_bits, ((0, pad_k), (0, pad_n), (0, 0)))
-        wn_bits = jnp.pad(wn_bits, ((0, pad_k), (0, pad_n), (0, 0)))
-    Mp, Kp, _ = xbits.shape
-    Np = wp_bits.shape[1]
-    grid = (Mp // block_m, Kp // block_k)
-
-    colgain = epi.get("colgain")
-    coladd = epi.get("coladd")
-    coeffs = epi.get("mean_coeffs")
-    cscale = epi.get("mean_scale")
-
-    operands = [xbits, wp_bits, wn_bits, jnp.asarray(prescale).reshape(1, 1)]
-    in_specs = [
-        pl.BlockSpec((block_m, block_k, W), lambda i, k: (i, k, 0)),
-        pl.BlockSpec((block_k, Np, W), lambda i, k: (k, 0, 0)),
-        pl.BlockSpec((block_k, Np, W), lambda i, k: (k, 0, 0)),
-        pl.BlockSpec((1, 1), lambda i, k: (0, 0)),
-    ]
-    if colgain is not None:
-        operands.append(_row_operand(colgain, Np, out_dtype))
-        in_specs.append(pl.BlockSpec((1, Np), lambda i, k: (0, 0)))
-    if coladd is not None:
-        operands.append(_row_operand(coladd, Np, out_dtype))
-        in_specs.append(pl.BlockSpec((1, Np), lambda i, k: (0, 0)))
-    if coeffs is not None:
-        P = coeffs.shape[-1]
-        operands.append(jnp.asarray(coeffs, jnp.float32).reshape(1, P))
-        in_specs.append(pl.BlockSpec((1, P), lambda i, k: (0, 0)))
-        operands.append(jnp.asarray(cscale, jnp.float32).reshape(1, 1))
-        in_specs.append(pl.BlockSpec((1, 1), lambda i, k: (0, 0)))
-
-    out = pl.pallas_call(
-        functools.partial(
-            _fused_kernel,
-            n_bits=n_bits,
-            block_k=block_k,
-            has_gain=colgain is not None,
-            has_add=coladd is not None,
-            has_corr=coeffs is not None,
-            out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, Np), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=[
-            _SCRATCH((block_m, Np, W), jnp.uint32),
-            _SCRATCH((block_m, Np, W), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return out[:M, :N]
+    the scalar rescale ``prescale`` (the composed path's
+    ``(sx * sw) / gain^2``) and the cast to ``out_dtype`` run before the
+    single writeback."""
+    return contract(
+        _planes(xbits), [_planes(wp_bits), _planes(wn_bits)],
+        mul=jnp.bitwise_and, combine=jnp.bitwise_or,
+        finish=functools.partial(_dual_value, n_bits=n_bits),
+        prescale=prescale, out_dtype=out_dtype,
+        interpret=interpret, **blocks,
+    )

@@ -1,48 +1,149 @@
-"""Shared Pallas scaffolding for multiplier-error backends.
+"""Shared Pallas scaffolding for contractions that cannot use the MXU.
 
-Backends whose error enters *per multiplication* with exact accumulation
-(truncated approximate multiplier, Mitchell log multiplier) cannot use
-the MXU: every product passes through a non-linear scalar op on the VPU.
-They share the entire TPU mapping — (bm x bn) output tiles resident in
-VMEM, a fori_loop walk over the K block forming rank-1 outer products
-elementwise, float32 accumulation — and differ only in that scalar op,
-so the pad/grid/pallas_call plumbing lives here once.
+Backends whose error enters *per multiplication* (truncated approximate
+multiplier, Mitchell log multiplier) and the stochastic-computing AND/OR
+stream contraction all pass every product through a non-linear scalar op
+on the VPU.  They share one TPU mapping, and differ only in the per-product
+op (``mul``), the accumulation (``combine``: add, or bitwise OR) and how
+the accumulator becomes the output (``finish``):
+
+* grid ``(M blocks, N blocks, K blocks)``, K innermost and sequential;
+* the activation is laid out ``[P, K, M]`` and the weight ``[P, K, N]``
+  (``P`` word planes, 1 for the float backends), so one K step reads row
+  ``i`` of both with ``pl.ds`` on the second-minor axis — never a dynamic
+  index on the lane axis, which the TPU compiler refuses;
+* a rank-1 update per K step into a ``(P, bm, bn)`` VMEM accumulator,
+  one per weight plane (the fused dual-plane kernels pass two).
+
+N is tiled, so an 11008-wide projection or a 151936-wide LM head never
+needs more than one ``(bm, bn)`` tile per plane in VMEM.
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.epilogue import apply_epilogue
-
-try:  # scratch memory spaces are TPU-specific; interpret mode accepts them
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _SCRATCH = None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, w_ref, o_ref, *, mul: Callable, block_k: int):
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def tile(n: int, block: int, align: int = 1):
+    """``(block size, padded extent)`` for an axis of length ``n``: one
+    block spanning the whole (``align``-padded) axis when it fits, else
+    ``block``-sized tiles over ``n`` padded to a multiple of ``block``."""
+    if n <= block:
+        n = _round_up(n, align)
+        return n, n
+    return block, _round_up(n, block)
+
+
+def _kernel(x_ref, *refs, mul, combine, finish, n_w: int, has_pre: bool):
+    w_refs = refs[:n_w]
+    pre_ref = refs[n_w] if has_pre else None
+    o_ref = refs[n_w + has_pre]
+    acc_refs = refs[n_w + has_pre + 1:]
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        for acc in acc_refs:
+            acc[...] = jnp.zeros_like(acc)
 
-    x = x_ref[...]  # [bm, bk] integer-valued float32
-    w = w_ref[...]  # [bk, bn]
+    n_planes, block_k, bm = x_ref.shape
+    for p in range(n_planes):
 
-    def body(i, acc):
-        return acc + mul(x[:, i, None], w[None, i, :])
+        def body(i, accs):
+            # column i of the activation tile, read as a sublane row and
+            # turned into a [bm, 1] column for the rank-1 update
+            xc = x_ref[p, pl.ds(i, 1), :].reshape(bm, 1)
+            return tuple(
+                combine(acc, mul(xc, w[p, pl.ds(i, 1), :]))
+                for acc, w in zip(accs, w_refs)
+            )
 
-    o_ref[...] += jax.lax.fori_loop(
-        0, block_k, body, jnp.zeros_like(o_ref)
-    )
+        accs = jax.lax.fori_loop(
+            0, block_k, body, tuple(acc[p] for acc in acc_refs)
+        )
+        for acc, v in zip(acc_refs, accs):
+            acc[p] = v
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _finish():
+        y = finish(*(acc[...] for acc in acc_refs))
+        if has_pre:
+            y = (y * pre_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = y
+
+
+def contract(
+    x,
+    ws: Sequence[jax.Array],
+    *,
+    mul: Callable,
+    combine: Callable,
+    finish: Callable,
+    prescale=None,
+    out_dtype=jnp.float32,
+    block_m: int = 128,
+    block_n: int = 512,
+    block_k: int = 128,
+    interpret: bool = False,
+):
+    """Elementwise-product contraction over word planes.
+
+    x: [P, M, K]; each of ``ws``: [P, K, N] (same dtype as ``x``).
+    Accumulates ``acc[p] = combine(acc[p], mul(x[p, :, k, None], w[p, k]))``
+    over k in order, one accumulator per weight plane, then writes
+    ``finish(*accs)`` ([M, N] f32) — times ``prescale`` ([M, 1] or a
+    scalar) and cast to ``out_dtype`` when a prescale is given.
+
+    ``mul`` must map zero operands to the accumulator's identity (K, M and
+    N padding is zero-filled).
+    """
+    P, M, K = x.shape
+    N = ws[0].shape[-1]
+    bm, Mp = tile(M, block_m, align=8)
+    bn, Np = tile(N, block_n)
+    bk, Kp = tile(K, block_k)
+    xt = jnp.pad(jnp.swapaxes(x, 1, 2), ((0, 0), (0, Kp - K), (0, Mp - M)))
+    ws = [jnp.pad(w, ((0, 0), (0, Kp - K), (0, Np - N))) for w in ws]
+
+    operands = [xt, *ws]
+    in_specs = [pl.BlockSpec((P, bk, bm), lambda i, j, k: (0, k, i))]
+    in_specs += [pl.BlockSpec((P, bk, bn), lambda i, j, k: (0, k, j))] * len(ws)
+    has_pre = prescale is not None
+    if has_pre:
+        pre = jnp.broadcast_to(
+            jnp.asarray(prescale, jnp.float32).reshape(-1, 1), (M, 1)
+        )
+        operands.append(jnp.pad(pre, ((0, Mp - M), (0, 0))))
+        in_specs.append(pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel, mul=mul, combine=combine, finish=finish,
+            n_w=len(ws), has_pre=has_pre,
+        ),
+        grid=(Mp // bm, Np // bn, Kp // bk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(
+            (Mp, Np), out_dtype if has_pre else jnp.float32
+        ),
+        scratch_shapes=[pltpu.VMEM((P, bm, bn), x.dtype) for _ in ws],
+        interpret=interpret,
+    )(*operands)
+    return out[:M, :N]
+
+
+def _sum_plane(acc):
+    return acc[0]
 
 
 def elementwise_matmul(
@@ -50,196 +151,31 @@ def elementwise_matmul(
     w,
     mul: Callable,
     *,
+    prescale=None,
+    out_dtype=jnp.float32,
     block_m: int = 128,
-    block_n: int = 128,
+    block_n: int = 512,
     block_k: int = 128,
     interpret: bool = False,
 ):
-    """[M,K] @ [K,N] -> [M,N] f32 with every product through ``mul(a, b)``.
+    """[M,K] @ [K,N] -> [M,N] f32 with every product through ``mul(a, b)``
+    and exact f32 accumulation in K order.
 
-    ``mul`` must be pure-jnp elementwise and map zero operands to zero
-    (K-padding is zero-filled).
+    With ``prescale`` ([M, 1] per-token rescale) the f32 accumulator is
+    multiplied by it and cast to ``out_dtype`` before the writeback — the
+    composed path's ``(acc * prescale).astype(dtype)``, fused.
     """
-    M, K = x.shape
-    N = w.shape[1]
-    block_m = min(block_m, M) or 1
-    block_n = min(block_n, N) or 1
-    block_k = min(block_k, K) or 1
-    pad_m = (-M) % block_m
-    pad_n = (-N) % block_n
-    pad_k = (-K) % block_k
-    if pad_m or pad_k:
-        x = jnp.pad(x, ((0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        w = jnp.pad(w, ((0, pad_k), (0, pad_n)))
-    Mp, Kp = x.shape
-    Np = w.shape[1]
-    grid = (Mp // block_m, Np // block_n, Kp // block_k)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, mul=mul, block_k=block_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
+    return contract(
+        x.astype(jnp.float32)[None], [w.astype(jnp.float32)[None]],
+        mul=mul, combine=jnp.add, finish=_sum_plane,
+        prescale=prescale, out_dtype=out_dtype,
+        block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=interpret,
-    )(x.astype(jnp.float32), w.astype(jnp.float32))
-    return out[:M, :N]
-
-
-# ---------------------------------------------------------------------------
-# Fused variant: matmul + MODEL-mode epilogue in one kernel
-# ---------------------------------------------------------------------------
-
-
-def _fused_kernel(
-    *refs,
-    mul: Callable,
-    block_k: int,
-    has_gain: bool,
-    has_add: bool,
-    has_corr: bool,
-    out_dtype,
-):
-    it = iter(refs)
-    x_ref = next(it)
-    w_ref = next(it)
-    pre_ref = next(it)
-    gain_ref = next(it) if has_gain else None
-    add_ref = next(it) if has_add else None
-    coeff_ref = next(it) if has_corr else None
-    cscale_ref = next(it) if has_corr else None
-    o_ref = next(it)
-    acc_ref = next(it)
-
-    k = pl.program_id(1)
-    nk = pl.num_programs(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]  # [bm, bk] f32
-    w = w_ref[...]  # [bk, N] f32
-
-    def body(i, acc):
-        return acc + mul(x[:, i, None], w[None, i, :])
-
-    acc_ref[...] += jax.lax.fori_loop(
-        0, block_k, body, jnp.zeros_like(acc_ref)
     )
 
-    @pl.when(k == nk - 1)
-    def _finish():
-        # identical op order to the composed path: f32 accumulator times
-        # the per-token prescale, cast down, then the chip + calibration
-        # epilogue in the output dtype
-        y = (acc_ref[...] * pre_ref[...]).astype(out_dtype)
-        y = apply_epilogue(
-            y,
-            colgain=gain_ref[...] if has_gain else None,
-            coladd=add_ref[...] if has_add else None,
-            mean_coeffs=coeff_ref[...] if has_corr else None,
-            mean_scale=cscale_ref[0, 0] if has_corr else None,
-        )
-        o_ref[...] = y
 
-
-def _row_operand(v, Np, dtype):
-    """Broadcast an epilogue vector (scalar, [N] or [1, N]) to a padded
-    [1, Np] kernel operand, zero-filled on padded columns."""
-    v = jnp.asarray(v, dtype).reshape(1, -1)
-    if v.shape[-1] == 1:
-        v = jnp.broadcast_to(v, (1, Np))
-        return v
-    return jnp.pad(v, ((0, 0), (0, Np - v.shape[-1])))
-
-
-def elementwise_matmul_fused(
-    x,
-    w,
-    mul: Callable,
-    prescale,
-    epi: dict,
-    out_dtype,
-    *,
-    block_m: int = 128,
-    block_k: int = 128,
-    interpret: bool = False,
-):
-    """Fused [M,K] @ [K,N] through ``mul`` with the MODEL-mode epilogue
-    applied on the accumulator tile before writeback.
-
-    ``prescale``: [M, 1] per-token rescale applied to the f32 accumulator
-    (the composed path's ``acc * (sx * sw / levels^2)``).  ``epi`` carries
-    optional ``colgain``/``coladd``/``mean_coeffs``/``mean_scale`` exactly
-    as :func:`repro.kernels.epilogue.apply_epilogue` expects them.
-
-    Grid is (M blocks, K blocks) with the full (padded) N per tile so the
-    per-token row max — the epilogue's activation scale — is computable
-    in-register.  K accumulation is strictly sequential, so the result is
-    bitwise identical to the unfused kernel's for any ``block_k``.
-    """
-    M, K = x.shape
-    N = w.shape[1]
-    block_m = min(block_m, M) or 1
-    block_k = min(block_k, K) or 1
-    pad_m = (-M) % block_m
-    pad_n = (-N) % 128 if N > 128 else 0
-    pad_k = (-K) % block_k
-    if pad_m or pad_k:
-        x = jnp.pad(x, ((0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        w = jnp.pad(w, ((0, pad_k), (0, pad_n)))
-    Mp, Kp = x.shape
-    Np = w.shape[1]
-    grid = (Mp // block_m, Kp // block_k)
-
-    pre = jnp.asarray(prescale).reshape(-1, 1)
-    pre = jnp.pad(pre, ((0, Mp - pre.shape[0]), (0, 0)))
-
-    colgain = epi.get("colgain")
-    coladd = epi.get("coladd")
-    coeffs = epi.get("mean_coeffs")
-    cscale = epi.get("mean_scale")
-
-    operands = [x.astype(jnp.float32), w.astype(jnp.float32), pre]
-    in_specs = [
-        pl.BlockSpec((block_m, block_k), lambda i, k: (i, k)),
-        pl.BlockSpec((block_k, Np), lambda i, k: (k, 0)),
-        pl.BlockSpec((block_m, 1), lambda i, k: (i, 0)),
-    ]
-    if colgain is not None:
-        operands.append(_row_operand(colgain, Np, out_dtype))
-        in_specs.append(pl.BlockSpec((1, Np), lambda i, k: (0, 0)))
-    if coladd is not None:
-        operands.append(_row_operand(coladd, Np, out_dtype))
-        in_specs.append(pl.BlockSpec((1, Np), lambda i, k: (0, 0)))
-    if coeffs is not None:
-        P = coeffs.shape[-1]
-        operands.append(jnp.asarray(coeffs, jnp.float32).reshape(1, P))
-        in_specs.append(pl.BlockSpec((1, P), lambda i, k: (0, 0)))
-        operands.append(jnp.asarray(cscale, jnp.float32).reshape(1, 1))
-        in_specs.append(pl.BlockSpec((1, 1), lambda i, k: (0, 0)))
-
-    out = pl.pallas_call(
-        functools.partial(
-            _fused_kernel,
-            mul=mul,
-            block_k=block_k,
-            has_gain=colgain is not None,
-            has_add=coladd is not None,
-            has_corr=coeffs is not None,
-            out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, Np), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=[_SCRATCH((block_m, Np), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
-    return out[:M, :N]
+def popcount_value(acc, n_bits: int):
+    """Stream value of an OR-accumulated word tile [W, bm, bn]: the
+    popcount summed over words, over the stream length."""
+    counts = jax.lax.population_count(acc).astype(jnp.int32)
+    return counts.astype(jnp.float32).sum(0) / n_bits

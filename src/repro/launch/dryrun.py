@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-)
-
 _DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this script:
@@ -37,6 +32,7 @@ __doc__ = _DOC
 import argparse
 import dataclasses
 import json
+import os
 import re
 import time
 import traceback
@@ -61,7 +57,6 @@ from repro.launch.mesh import (
     ICI_BW_PER_LINK,
     PEAK_FLOPS_BF16,
     make_production_mesh,
-    use_mesh,
 )
 from repro.models import build_model
 from repro.runtime import sharding as shard_lib
@@ -266,7 +261,7 @@ def lower_cell(
         )
         rng_sds = jax.ShapeDtypeStruct((2,), jnp.uint32)
         step_fn = step_lib.make_train_step(model, approx, tcfg)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(
                 step_fn,
                 in_shardings=(state_sh, batch_sh, shard_lib.replicated(mesh)),
@@ -291,7 +286,7 @@ def lower_cell(
             )
             return out.logits[:, -1]
 
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(prefill, in_shardings=(params_sh, batch_sh)).lower(
                 params_sds, batch_sds
             )
@@ -327,7 +322,7 @@ def lower_cell(
             ctx=ctx, flash=fused,
         )
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(
             decode,
             in_shardings=(params_sh, cache_sh, tok_sh, shard_lib.replicated(mesh)),
@@ -553,7 +548,18 @@ def run_cell(
 # ---------------------------------------------------------------------------
 
 
+def force_host_devices() -> None:
+    """Give the CPU backend the fake devices a production mesh needs
+    (``REPRO_DRYRUN_XLA_FLAGS``, default 512).  Takes effect only before
+    JAX's first backend initialization, so entry points call it first
+    thing; importing this module changes nothing."""
+    os.environ["XLA_FLAGS"] = os.environ.get(
+        "REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=512"
+    )
+
+
 def main() -> None:
+    force_host_devices()
     ap = argparse.ArgumentParser(description=_DOC)
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -6,49 +6,27 @@ never touches jax device state — required because the dry-run must set
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax wants explicit ``axis_types`` (Auto for our meshes); older
-    releases (<= 0.4.x) have neither ``jax.sharding.AxisType`` nor the
-    kwarg — fall back to the plain call there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for jit/shard_map bodies.
-
-    ``jax.set_mesh`` where it exists; on older jax the ``Mesh`` object is
-    itself the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, n_pod: int = 0):
     """Small host-device mesh for distributed CPU tests."""
     if n_pod:
-        return make_mesh_compat((n_pod, n_data, n_model), ("pod", "data", "model"))
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+        return _auto_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline model (per chip)

@@ -52,6 +52,7 @@ import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ApproxConfig, parse_site_backends
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.models.transformer import ALL_SITES
 from repro.runtime.engine import (
@@ -163,6 +164,7 @@ def main() -> None:
     # legacy flag of the old static driver, kept as an alias for --slots
     ap.add_argument("--batch", type=int, default=0, help=argparse.SUPPRESS)
     args = ap.parse_args()
+    compile_cache.enable()
     if args.batch:
         args.slots = args.batch
 

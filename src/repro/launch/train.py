@@ -9,9 +9,11 @@ Declarative multi-phase pipeline (paper recipe with adaptive calibration):
       --backend analog --phase exact:10 \\
       --phase inject:70:calib=adaptive,drift=0.05 --phase model:20:lr=0.5
 
-On a real TPU deployment the same driver runs under
-``jax.distributed.initialize()`` with the production mesh; device-count
-gating below keeps CPU runs on a single device.
+This entry point runs on one device: the Trainer places the model, optimizer
+state and batches on JAX's default device (one TPU chip, or the CPU).  It
+builds no mesh and never calls ``jax.distributed.initialize()``; the
+production-mesh shardings in :mod:`repro.runtime.sharding` are exercised
+only by the dry-run (:mod:`repro.launch.dryrun`).
 """
 from __future__ import annotations
 
@@ -34,6 +36,7 @@ from repro.configs.base import (
 )
 from repro.models.transformer import ALL_SITES
 from repro.data import SyntheticLM
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.runtime.trainer import Trainer
 
@@ -90,6 +93,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--report", default=None, help="write JSON report here")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)

@@ -74,7 +74,11 @@ def adamw_init(params, compress: str = "none"):
             }
         return jnp.zeros(x.shape, jnp.float32)
 
-    master = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params)
+    # a buffer of its own even where params are already f32: a train step
+    # that donates its state cannot donate one buffer twice
+    master = jax.tree_util.tree_map(
+        lambda x: jnp.array(x, jnp.float32, copy=True), params
+    )
     return {
         "m": jax.tree_util.tree_map(init_m, params),
         "v": jax.tree_util.tree_map(init_v, params),

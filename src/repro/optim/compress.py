@@ -26,7 +26,6 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -127,8 +126,8 @@ def crosspod_reduce(
         )
 
     specs = jax.tree_util.tree_map(lambda _: P(), grads)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(specs, specs), out_specs=(specs, specs),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(grads, ef_state)

@@ -439,6 +439,7 @@ class Engine:
 
         # accounting (steady-state timers exclude compile time)
         self.compile_s = 0.0
+        self.compiles: List[Tuple[Tuple, float]] = []  # (graph key, seconds)
         self.prefill_s = 0.0
         self.decode_s = 0.0
         self.prefill_tokens = 0
@@ -474,6 +475,7 @@ class Engine:
         compiled = self.fns.trace_counts.get(key, 0) > before
         if compiled:
             self.compile_s += dt
+            self.compiles.append((key, dt))
         return out, dt, compiled
 
     def _decode_key_fn(self, approx: ApproxConfig, chip_aware: bool = False):

@@ -172,10 +172,7 @@ def maybe_constrain(x, spec: P):
     Outside any mesh (CPU unit tests) this is an identity, which keeps the
     model code mesh-agnostic.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        return jax.lax.with_sharding_constraint(x, validated(spec, x.shape, mesh))
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
+    return jax.lax.with_sharding_constraint(x, validated(spec, x.shape, mesh))

@@ -96,7 +96,8 @@ class Trainer:
 
         self.plan = PhasePlan.from_configs(approx, tcfg)
         self.controller = CalibrationController(self.plan, approx)
-        self.steps = StepCache(model, approx, tcfg)
+        # every step's state argument is the loop's only reference to it
+        self.steps = StepCache(model, approx, tcfg, donate_state=True)
         # variation-aware phases (Phase.fleet > 0): seeded device fleets,
         # built lazily per distinct size.  The fleet seed is decoupled
         # from the data/init seed so a chip resample sweep holds data

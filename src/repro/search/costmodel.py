@@ -47,18 +47,8 @@ INT8_BWD_MAC_ENERGY = 0.25
 
 
 def _per_site_macs(cfg: ModelConfig, seq_len: int, batch: int):
-    # launch.dryrun force-sets XLA_FLAGS at import (it must precede jax
-    # init when run as a CLI); as a library import that side effect must
-    # not leak into this process' environment (child processes would
-    # inherit 512 fake host devices).
-    prev = os.environ.get("XLA_FLAGS")
-    try:
-        from repro.launch import dryrun
-    finally:
-        if prev is None:
-            os.environ.pop("XLA_FLAGS", None)
-        else:
-            os.environ["XLA_FLAGS"] = prev
+    from repro.launch import dryrun  # heavy: only when costs are asked for
+
     return dryrun.per_site_macs(cfg, seq_len=seq_len, batch=batch)
 
 

@@ -318,11 +318,17 @@ class StepCache(CompiledFnCache):
     own.
     """
 
-    def __init__(self, model: Model, approx: ApproxConfig, tcfg: TrainConfig):
+    def __init__(self, model: Model, approx: ApproxConfig, tcfg: TrainConfig,
+                 *, donate_state: bool = False):
         super().__init__()
         self.model = model
         self.approx = approx
         self.tcfg = tcfg
+        # train/calibration steps consume their state argument: a caller
+        # that holds no other reference to it (the Trainer) lets XLA write
+        # the new state into the old one's buffers, instead of holding two
+        # copies of params, master weights and moments at the step's peak
+        self._donate = {"donate_argnums": (0,)} if donate_state else {}
 
     # ------------------------------------------------------------------
     def _resolve(self, mode: Optional[TrainMode]) -> ApproxConfig:
@@ -367,6 +373,7 @@ class StepCache(CompiledFnCache):
                 chip_aware=chip_aware, switch_aware=switch_aware,
                 bwd_aware=bwd_aware,
             ),
+            **self._donate,
         )
 
     def calibration(self, *, chip_aware: bool = False) -> Callable:
@@ -378,6 +385,7 @@ class StepCache(CompiledFnCache):
             lambda: make_calibration_step(
                 self.model, self.approx, self.tcfg, chip_aware=chip_aware
             ),
+            **self._donate,
         )
 
     def eval(self, *, chip_aware: bool = False,

@@ -1,87 +1,9 @@
 """Shared fixtures.  NOTE: no XLA device-count forcing here — smoke tests
 must see the real single CPU device; distributed tests spawn subprocesses
 with their own XLA_FLAGS (see test_distributed.py).
-
-Also installs a minimal deterministic stand-in for ``hypothesis`` when the
-real package (declared in pyproject.toml's test extra) is not installed,
-so the property tests still collect and run everywhere: the stub drives
-each ``@given`` test with the strategy boundary values plus a fixed-seed
-random sample of ``max_examples`` draws.
 """
-import functools
-import inspect
-import random
-import sys
-import types
-import zlib
-
 import jax
 import pytest
-
-
-def _install_hypothesis_stub():
-    class _Integers:
-        def __init__(self, lo, hi):
-            self.lo, self.hi = int(lo), int(hi)
-
-        def boundary(self):
-            return [self.lo, self.hi] if self.lo != self.hi else [self.lo]
-
-        def sample(self, rnd):
-            return rnd.randint(self.lo, self.hi)
-
-    def settings(max_examples=20, deadline=None, **_ignored):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-
-        return deco
-
-    def given(**strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_stub_max_examples", 20)
-                rnd = random.Random(zlib.crc32(fn.__name__.encode()))
-                names = sorted(strategies)
-                cases = []
-                for name in names:  # boundary sweep, one axis at a time
-                    for v in strategies[name].boundary():
-                        cases.append(
-                            {
-                                k: (v if k == name else strategies[k].boundary()[0])
-                                for k in names
-                            }
-                        )
-                while len(cases) < n:
-                    cases.append({k: strategies[k].sample(rnd) for k in names})
-                for case in cases[: max(n, len(names) * 2)]:
-                    fn(*args, **kwargs, **case)
-
-            # hide the strategy parameters from pytest's fixture resolution
-            # (the real hypothesis does the same via its own signature)
-            sig = inspect.signature(fn)
-            params = [p for p in sig.parameters.values() if p.name not in strategies]
-            wrapper.__signature__ = sig.replace(parameters=params)
-            del wrapper.__wrapped__
-            return wrapper
-
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers = lambda min_value=0, max_value=0: _Integers(min_value, max_value)
-    mod.strategies = st
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st
-
-
-try:  # pragma: no cover - prefer the real property-testing engine
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    _install_hypothesis_stub()
 
 
 @pytest.fixture(scope="session")

@@ -21,7 +21,7 @@ from repro.ckpt import CheckpointManager
 from repro.configs import get_smoke_config
 from repro.configs.base import AnalogParams, ApproxConfig, Backend, TrainConfig, TrainMode
 from repro.data import SyntheticLM
-from repro.launch.mesh import make_debug_mesh, make_mesh_compat, use_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.models import build_model
 from repro.optim.compress import crosspod_reduce, init_compression_state, int8_allreduce
 from repro.runtime import sharding as shard_lib
@@ -59,7 +59,7 @@ batch = {
     k: jax.device_put(v, NamedSharding(mesh, shard_lib.batch_spec(v.shape, mesh)))
     for k, v in batch.items()
 }
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     step = jax.jit(step_lib.make_train_step(model, approx, tcfg))
     losses = []
     for s in range(3):
@@ -102,7 +102,7 @@ with tempfile.TemporaryDirectory() as d:
         for k, v in data.batch_at(4).items()
     }
     tcfg2 = TrainConfig(total_steps=10, warmup_steps=1, learning_rate=1e-3, fsdp=True)
-    with use_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         step2 = jax.jit(step_lib.make_train_step(model, approx, tcfg2))
         restored, met2 = step2(restored, batch2, jax.random.PRNGKey(9))
     results["elastic_resume_loss_finite"] = bool(np.isfinite(float(met2["loss"])))
@@ -129,7 +129,7 @@ batch3_sds = model.input_specs(8, 16)
 batch3_sh = jax.tree_util.tree_map(
     lambda s: NamedSharding(mesh3, shard_lib.batch_spec(s.shape, mesh3)), batch3_sds
 )
-with use_mesh(mesh3):
+with jax.set_mesh(mesh3):
     lowered = jax.jit(
         step_lib.make_train_step(model, approx, tcfg),
         in_shardings=(sh3, batch3_sh, shard_lib.replicated(mesh3)),
@@ -143,8 +143,9 @@ results["multipod_has_collectives"] = any(
 # ---------------------------------------------------------------------------
 # 4. compressed cross-pod all-reduce with error feedback
 # ---------------------------------------------------------------------------
-pod_mesh = make_mesh_compat((8,), ("pod",))
-from jax.experimental.shard_map import shard_map
+pod_mesh = jax.make_mesh(
+    (8,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,)
+)
 
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))  # row i = pod i's grad
 
@@ -158,10 +159,10 @@ ef = jnp.zeros((8, 64))
 true_mean = x.mean(0)
 errs = []
 for it in range(6):
-    fn = shard_map(
+    fn = jax.shard_map(
         per_pod, mesh=pod_mesh,
         in_specs=(P("pod"), P("pod")), out_specs=(P(None), P("pod")),
-        check_rep=False,
+        check_vma=False,
     )
     out, ef = fn(x, ef)
     errs.append(float(jnp.abs(out[0] - true_mean).max()))

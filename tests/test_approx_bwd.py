@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.ckpt import CheckpointManager
@@ -274,9 +273,9 @@ def test_bf16_error_feedback_converges():
         out, e2 = int8_allreduce(g[0], e[0], "pod")
         return out[None], e2[None]
 
-    reduce = shard_map(
+    reduce = jax.shard_map(
         body, mesh=mesh, in_specs=(P("pod"), P("pod")),
-        out_specs=(P("pod"), P("pod")), check_rep=False,
+        out_specs=(P("pod"), P("pod")), check_vma=False,
     )
 
     @jax.jit

@@ -440,8 +440,8 @@ def test_switch_matches_static_random_maps(micro_model, code):
     """Property: for ANY site map, switch dispatch matches static
     dispatch to float32 ulp (see ``_ulp_close``) at the model level.
     The map is derived from one integer
-    draw (base-len(table) digits, one per site) so the stub strategy's
-    integers-only vocabulary covers the full map space."""
+    draw (base-len(table) digits, one per site), so one integer strategy
+    covers the full map space."""
     cfg, model, params, batch = micro_model
     t = switch_lib.table()
     digits, c = [], code

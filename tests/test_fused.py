@@ -1,7 +1,8 @@
 """Fused MODEL-mode hot path vs the composed oracle.
 
-The fused kernels (matmul + chip perturbation + calibration correction
-in one pass) must be BIT-identical to the composed sequence
+The fused path (one kernel for the matmul, rescale and cast, then chip
+perturbation + calibration correction) must be BIT-identical to the
+composed sequence
 ``quantize -> matmul -> apply_chip -> predict_mean subtract`` — the
 composed path is the repo's accuracy oracle, so any drift in the fused
 path would silently change what "the hardware computes".  Exactness is
