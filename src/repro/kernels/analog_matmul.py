@@ -101,6 +101,7 @@ def _analog_call(x, ws, array_size, adc_bits, adc_range, prescale, out_dtype,
         ),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32) for _ in ws],
         interpret=interpret,
+        name="analog_matmul",
     )(*operands)
     return out[:M, :N]
 

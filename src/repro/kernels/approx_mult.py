@@ -40,5 +40,5 @@ def approx_mult_matmul(
     drop_scale = float(1 << (2 * perforate))
     return elementwise_matmul(
         x, w, lambda a, b: _approx_mul(a, b, drop_scale),
-        prescale=prescale, out_dtype=out_dtype, interpret=interpret, **blocks,
+        name="approx_mult_matmul", prescale=prescale, out_dtype=out_dtype, interpret=interpret, **blocks,
     )

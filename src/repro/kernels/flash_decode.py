@@ -122,6 +122,7 @@ def flash_decode(q, cache_k, cache_v, pos_vec, *, interpret: bool = False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, dh), jnp.float32),
         interpret=interpret,
+        name="flash_decode",
     )(
         jnp.asarray(pos_vec, jnp.int32).reshape(B),
         q,

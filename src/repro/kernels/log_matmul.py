@@ -29,6 +29,6 @@ def log_matmul(
     With ``prescale`` ([M, 1]) the accumulator is rescaled and cast to
     ``out_dtype`` in the kernel (the fused MODEL-mode entry point)."""
     return elementwise_matmul(
-        x, w, ref.mitchell_mul,
+        x, w, ref.mitchell_mul, name="log_matmul",
         prescale=prescale, out_dtype=out_dtype, interpret=interpret, **blocks,
     )

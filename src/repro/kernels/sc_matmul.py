@@ -46,7 +46,7 @@ def sc_matmul_packed(xbits, wbits, n_bits: int, *, interpret: bool = False,
         _planes(xbits), [_planes(wbits)],
         mul=jnp.bitwise_and, combine=jnp.bitwise_or,
         finish=functools.partial(popcount_value, n_bits=n_bits),
-        interpret=interpret, **blocks,
+        name="sc_matmul", interpret=interpret, **blocks,
     )
 
 
@@ -63,6 +63,6 @@ def sc_matmul_packed_fused(
         _planes(xbits), [_planes(wp_bits), _planes(wn_bits)],
         mul=jnp.bitwise_and, combine=jnp.bitwise_or,
         finish=functools.partial(_dual_value, n_bits=n_bits),
-        prescale=prescale, out_dtype=out_dtype,
+        name="sc_matmul_fused", prescale=prescale, out_dtype=out_dtype,
         interpret=interpret, **blocks,
     )

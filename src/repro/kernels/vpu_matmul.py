@@ -88,6 +88,7 @@ def contract(
     mul: Callable,
     combine: Callable,
     finish: Callable,
+    name: str,
     prescale=None,
     out_dtype=jnp.float32,
     block_m: int = 128,
@@ -104,7 +105,8 @@ def contract(
     scalar) and cast to ``out_dtype`` when a prescale is given.
 
     ``mul`` must map zero operands to the accumulator's identity (K, M and
-    N padding is zero-filled).
+    N padding is zero-filled).  ``name`` is the kernel's name in a
+    profile (the calling backend's).
     """
     P, M, K = x.shape
     N = ws[0].shape[-1]
@@ -138,6 +140,7 @@ def contract(
         ),
         scratch_shapes=[pltpu.VMEM((P, bm, bn), x.dtype) for _ in ws],
         interpret=interpret,
+        name=name,
     )(*operands)
     return out[:M, :N]
 
@@ -151,6 +154,7 @@ def elementwise_matmul(
     w,
     mul: Callable,
     *,
+    name: str,
     prescale=None,
     out_dtype=jnp.float32,
     block_m: int = 128,
@@ -167,7 +171,7 @@ def elementwise_matmul(
     """
     return contract(
         x.astype(jnp.float32)[None], [w.astype(jnp.float32)[None]],
-        mul=mul, combine=jnp.add, finish=_sum_plane,
+        mul=mul, combine=jnp.add, finish=_sum_plane, name=name,
         prescale=prescale, out_dtype=out_dtype,
         block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=interpret,

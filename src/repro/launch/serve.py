@@ -4,9 +4,9 @@ Thin CLI over :class:`repro.runtime.engine.Engine`: builds a queue of
 synthetic requests with mixed prompt/generation lengths and per-request
 backends, serves it with continuous batching (slot admit/evict, bucketed
 bulk prefill, one compiled decode step per serving config), and reports
-prefill/decode/total tok/s, p50/p99 per-token latency, slot utilization,
-and compile time (reported separately — it never pollutes the
-steady-state throughput numbers).
+prefill/decode/total tok/s, the engine's span table (count, total and
+self seconds per span), slot utilization, and compile time (reported
+separately — it never pollutes the steady-state throughput numbers).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \\
       --requests 12 --slots 4 --prompt-len 16 --gen 32 \\

@@ -80,6 +80,7 @@ from repro.hw import DriftModel, Fleet
 from repro.hw import drift as drift_lib
 from repro.models import decode as D
 from repro.models.model import Model
+from repro.runtime.spans import Spans
 from repro.training.losses import lm_loss
 from repro.training.steps import CompiledFnCache
 
@@ -204,7 +205,6 @@ class _Active:
     t_admit: float
     prefill_s: float = 0.0
     tokens: List[int] = dataclasses.field(default_factory=list)
-    latencies: List[float] = dataclasses.field(default_factory=list)
     logits: List[np.ndarray] = dataclasses.field(default_factory=list)
 
 
@@ -271,6 +271,16 @@ class Engine:
     the queue to completion and returns per-request results.  Completed
     requests stream through the optional ``stream`` callback as
     ``stream(rid, token, done)``.
+
+    ``spans`` (:mod:`repro.runtime.spans`) times the engine's own work:
+    ``engine.step``; ``engine.admit`` (the admission loop, whose self
+    time is the scan of the pending queue; counters ``engine.scanned``
+    and ``engine.admitted``); every compiled call as ``engine.<kind>``
+    (``prefill``, ``decode``, ``reset``, ``recalibrate``, ``probe``, or
+    ``compile`` for a call that traced) with children ``.dispatch`` and
+    ``.wait``; ``engine.fetch`` (logits to the host) and
+    ``engine.sample`` (the per-row sampling loop).  ``prefill_s``,
+    ``decode_s`` and ``compile_s`` read its totals.
     """
 
     def __init__(
@@ -437,11 +447,11 @@ class Engine:
         self._sampler = np.random.default_rng(seed)
         self._tick = 0
 
-        # accounting (steady-state timers exclude compile time)
-        self.compile_s = 0.0
+        # accounting: the spans time every compiled call, a call that
+        # traced under ``engine.compile``, so the steady-state timers
+        # (prefill_s, decode_s) exclude compile time
+        self.spans = Spans()
         self.compiles: List[Tuple[Tuple, float]] = []  # (graph key, seconds)
-        self.prefill_s = 0.0
-        self.decode_s = 0.0
         self.prefill_tokens = 0
         self.decode_tokens = 0
         self.decode_steps = 0
@@ -459,24 +469,40 @@ class Engine:
         # loop); the queue carries (request, lane-key) pairs
         self.pending.append((req, resolve_approx(req, self.approx_base)))
 
+    @property
+    def prefill_s(self) -> float:
+        return self.spans.total("engine.prefill")
+
+    @property
+    def decode_s(self) -> float:
+        return self.spans.total("engine.decode")
+
+    @property
+    def compile_s(self) -> float:
+        return self.spans.total("engine.compile")
+
     # -- compiled steps --------------------------------------------------
-    def _call(self, key, fn, *args):
+    def _call(self, kind: str, key, fn, *args, **attrs):
         """Invoke a compiled step; returns (out, seconds, compiled?).
 
-        Blocks on the FULL output (cache included, not just logits)
-        before stopping the clock, and flags calls that traced so compile
-        time never pollutes steady-state throughput numbers.
+        The call is the span ``engine.<kind>``, its children
+        ``.dispatch`` (until ``fn`` returns) and ``.wait`` (blocking on
+        the FULL output, cache included, not just logits).  A call that
+        traced ends as ``engine.compile`` instead, so compile time never
+        pollutes steady-state throughput numbers.
         """
         before = self.fns.trace_counts.get(key, 0)
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        compiled = self.fns.trace_counts.get(key, 0) > before
+        name = "engine.compile" if before == 0 else f"engine.{kind}"
+        with self.spans.span(name, **attrs) as sp:
+            with self.spans.span(name + ".dispatch"):
+                out = fn(*args)
+            with self.spans.span(name + ".wait"):
+                jax.block_until_ready(out)
+            compiled = self.fns.trace_counts.get(key, 0) > before
+            sp.name = "engine.compile" if compiled else f"engine.{kind}"
         if compiled:
-            self.compile_s += dt
-            self.compiles.append((key, dt))
-        return out, dt, compiled
+            self.compiles.append((key, sp.seconds))
+        return out, sp.seconds, compiled
 
     def _decode_key_fn(self, approx: ApproxConfig, chip_aware: bool = False):
         key = ("decode", self.n_slots, self.max_seq, approx,
@@ -746,7 +772,7 @@ class Engine:
         """
         key, fn = self._recalib_key_fn(lane.approx)
         (calib, loss), _, _ = self._call(
-            key, fn, self.params,
+            "recalibrate", key, fn, self.params,
             jnp.asarray(self.probe["tokens"]), jnp.asarray(self.probe["labels"]),
             self._next_rng(), lane.chip,
         )
@@ -856,7 +882,7 @@ class Engine:
     def _probe_raw(self, lane: _Lane) -> float:
         key, fn = self._probe_raw_key_fn(lane.approx)
         loss, _, _ = self._call(
-            key, fn, self.params,
+            "probe", key, fn, self.params,
             jnp.asarray(self.probe["tokens"]), jnp.asarray(self.probe["labels"]),
             self._next_rng(), lane.chip,
         )
@@ -865,7 +891,7 @@ class Engine:
     def _probe_corrected_loss(self, lane: _Lane) -> float:
         pkey, pfn = self._probe_key_fn(lane.approx)
         closs, _, _ = self._call(
-            pkey, pfn, self.params,
+            "probe", pkey, pfn, self.params,
             jnp.asarray(self.probe["tokens"]), jnp.asarray(self.probe["labels"]),
             self._next_rng(), lane.chip, lane.calib,
         )
@@ -891,7 +917,6 @@ class Engine:
         self.results[st.req.rid] = {
             "tokens": list(st.tokens),
             "prefill_s": st.prefill_s,
-            "latencies_s": list(st.latencies),
             "backend": st.req.backend,
             "emulated": lane.approx.active,
             "chip": lane.chip_id if lane.chip is not None else None,
@@ -907,7 +932,7 @@ class Engine:
         # fixed point — while it sits idle, one more reason per-tensor-
         # scale emulation is only exact at batch 1.)
         key, fn = self._reset_key_fn()
-        out, _, _ = self._call(key, fn, lane.cache, jnp.int32(slot))
+        out, _, _ = self._call("reset", key, fn, lane.cache, jnp.int32(slot))
         lane.cache = out
         lane.tokens[slot, 0] = 0
         lane.pos[slot] = 0
@@ -947,13 +972,14 @@ class Engine:
             )
             if chip_aware:
                 args += (lane.chip, lane.calib)
-        (last, cache), dt, compiled = self._call(key, fn, *args)
+        (last, cache), dt, compiled = self._call(
+            "prefill", key, fn, *args, rid=req.rid, bucket=L
+        )
         lane.cache = cache
         if chip_aware:
             self._advance_chip(lane, P)
         if not compiled:  # steady-state accounting: compiling calls are
-            self.prefill_s += dt  # excluded from both time AND tokens
-            self.prefill_tokens += P
+            self.prefill_tokens += P  # excluded from both time AND tokens
 
         # per-request prefill_s is a steady-state number: a call that
         # traced reports its (much larger) duration under compile_s only
@@ -961,21 +987,22 @@ class Engine:
             req=req, t_admit=time.perf_counter(),
             prefill_s=0.0 if compiled else dt,
         )
-        logits_row = np.asarray(last)
-        if self.collect_logits:
-            st.logits.append(logits_row)
-        st.tokens.append(self._sample(req, logits_row))
-        lane.slots[slot] = st
-        lane.tokens[slot, 0] = st.tokens[-1]
-        lane.pos[slot] = P
-        if lane.switch:
-            lane.site_idx[slot] = idx_row
-
+        with self.spans.span("engine.fetch"):
+            logits_row = np.asarray(last)
         events: List[Dict[str, Any]] = []
-        done = len(st.tokens) >= req.max_new_tokens
-        self._emit(st, events, done)
-        if done:
-            self._finish(lane, slot)
+        with self.spans.span("engine.sample"):
+            if self.collect_logits:
+                st.logits.append(logits_row)
+            st.tokens.append(self._sample(req, logits_row))
+            lane.slots[slot] = st
+            lane.tokens[slot, 0] = st.tokens[-1]
+            lane.pos[slot] = P
+            if lane.switch:
+                lane.site_idx[slot] = idx_row
+            done = len(st.tokens) >= req.max_new_tokens
+            self._emit(st, events, done)
+            if done:
+                self._finish(lane, slot)
         return events
 
     def _decode_lane(self, lane: _Lane) -> List[Dict[str, Any]]:
@@ -996,34 +1023,33 @@ class Engine:
             )
             if chip_aware:
                 args += (lane.chip, lane.calib)
-        (logits, cache), dt, compiled = self._call(key, fn, *args)
+        (logits, cache), _, compiled = self._call("decode", key, fn, *args)
         lane.cache = cache
         if chip_aware:
             # the device ages by the tokens it actually produced
             self._advance_chip(lane, lane.n_active())
-        logits_np = np.asarray(logits)
+        with self.spans.span("engine.fetch"):
+            logits_np = np.asarray(logits)
 
         events: List[Dict[str, Any]] = []
         n_active = 0
-        for i, st in enumerate(lane.slots):
-            if st is None:
-                continue
-            n_active += 1
-            row = logits_np[i]
-            if self.collect_logits:
-                st.logits.append(row)
-            st.tokens.append(self._sample(st.req, row))
-            if not compiled:
-                st.latencies.append(dt)
-            lane.tokens[i, 0] = st.tokens[-1]
-            lane.pos[i] += 1
-            done = len(st.tokens) >= st.req.max_new_tokens
-            self._emit(st, events, done)
-            if done:
-                self._finish(lane, i)
+        with self.spans.span("engine.sample"):
+            for i, st in enumerate(lane.slots):
+                if st is None:
+                    continue
+                n_active += 1
+                row = logits_np[i]
+                if self.collect_logits:
+                    st.logits.append(row)
+                st.tokens.append(self._sample(st.req, row))
+                lane.tokens[i, 0] = st.tokens[-1]
+                lane.pos[i] += 1
+                done = len(st.tokens) >= st.req.max_new_tokens
+                self._emit(st, events, done)
+                if done:
+                    self._finish(lane, i)
         self.decode_steps += 1
         if not compiled:  # steady-state accounting (see _admit)
-            self.decode_s += dt
             self.decode_tokens += n_active
         return events
 
@@ -1035,48 +1061,54 @@ class Engine:
         ``external_recal``, flagging the lane and notifying the
         recalibration service instead).  Externally pushed coefficients
         are applied first, at this step boundary, never mid-step."""
-        events: List[Dict[str, Any]] = []
-        self.apply_pushes()
-        deferred: deque = deque()
-        while self.pending:
-            req, approx = self.pending.popleft()
-            lane = self._lane_for(
-                self._lane_key(approx), switch=self.switch and approx.active
-            )
-            free = lane.free_slots() if lane is not None else []
-            if free:
-                events += self._admit(lane, free[0], req, approx)
-            else:
-                deferred.append((req, approx))
-        self.pending = deferred
-
-        active = sum(l.n_active() for l in self.lanes.values())
-        capacity = max(1, self.n_slots * len(self.lanes))
-        if active:
-            self._util.append((active, capacity))
-        for lane in list(self.lanes.values()):
-            if lane.controller is not None and lane.n_active():
-                lane.tick += 1
-                if lane.controller.begin_step(lane.tick):
-                    # drift detection in the loop: the controller halves
-                    # its interval when the probe loss moves (the chip is
-                    # drifting), backs off while it holds steady
-                    if self.external_recal:
-                        # off-hot-path recalibration: flag the lane stale
-                        # and hand the refit to the service; coefficients
-                        # come back through push_calib (one outstanding
-                        # job per lane at a time)
-                        if not lane.awaiting_recal:
-                            lane.awaiting_recal = True
-                            if self.on_recal_due is not None:
-                                self.on_recal_due(lane.key, lane)
+        with self.spans.span("engine.step"):
+            events: List[Dict[str, Any]] = []
+            self.apply_pushes()
+            with self.spans.span("engine.admit"):
+                deferred: deque = deque()
+                scanned = len(self.pending)
+                while self.pending:
+                    req, approx = self.pending.popleft()
+                    lane = self._lane_for(
+                        self._lane_key(approx),
+                        switch=self.switch and approx.active,
+                    )
+                    free = lane.free_slots() if lane is not None else []
+                    if free:
+                        events += self._admit(lane, free[0], req, approx)
                     else:
-                        lane.controller.record(
-                            lane.tick, self._recalibrate(lane)
-                        )
-            if lane.n_active():
-                events += self._decode_lane(lane)
-        return events
+                        deferred.append((req, approx))
+                self.spans.count("engine.scanned", scanned)
+                self.spans.count("engine.admitted", scanned - len(deferred))
+                self.pending = deferred
+
+            active = sum(l.n_active() for l in self.lanes.values())
+            capacity = max(1, self.n_slots * len(self.lanes))
+            if active:
+                self._util.append((active, capacity))
+            for lane in list(self.lanes.values()):
+                if lane.controller is not None and lane.n_active():
+                    lane.tick += 1
+                    if lane.controller.begin_step(lane.tick):
+                        # drift detection in the loop: the controller halves
+                        # its interval when the probe loss moves (the chip is
+                        # drifting), backs off while it holds steady
+                        if self.external_recal:
+                            # off-hot-path recalibration: flag the lane stale
+                            # and hand the refit to the service; coefficients
+                            # come back through push_calib (one outstanding
+                            # job per lane at a time)
+                            if not lane.awaiting_recal:
+                                lane.awaiting_recal = True
+                                if self.on_recal_due is not None:
+                                    self.on_recal_due(lane.key, lane)
+                        else:
+                            lane.controller.record(
+                                lane.tick, self._recalibrate(lane)
+                            )
+                if lane.n_active():
+                    events += self._decode_lane(lane)
+            return events
 
     def run(self, requests: Optional[Sequence[Request]] = None) -> Dict[int, Dict]:
         """Drive the queue to completion; returns {rid: result}."""
@@ -1092,9 +1124,6 @@ class Engine:
         return self.fns.stats()
 
     def metrics(self) -> Dict[str, Any]:
-        lat = [
-            t for r in self.results.values() for t in r["latencies_s"]
-        ]
         util = (
             float(np.mean([a / c for a, c in self._util])) if self._util else 0.0
         )
@@ -1114,14 +1143,14 @@ class Engine:
             "compile_s": self.compile_s,
             "fused": self.fused,
             "switch": self.switch,
-            "p50_ms": float(np.percentile(lat, 50) * 1e3) if lat else 0.0,
-            "p99_ms": float(np.percentile(lat, 99) * 1e3) if lat else 0.0,
             "slot_util": util,
             "recalibrations": self.recalibrations,
             "recal_pushes": self.recal_pushes,
             "site_mask": list(self.site_mask),
             "fleet_chips": len(self.fleet) if self.fleet is not None else 0,
             "compile_stats": self.compile_stats,
+            # count, total and self seconds per span name, and the counters
+            **self.spans.snapshot(),
         }
 
     def fleet_report(self) -> List[Dict[str, Any]]:

@@ -24,6 +24,10 @@ Responsibilities beyond calling the step functions:
   ``straggler_factor``x the EWMA *of the preceding steps* are logged and
   counted — on a real multi-host deployment this signal feeds the
   work-stealing data pipeline (any host can regenerate any shard).
+* **Spans** (``Trainer.spans``, :mod:`repro.runtime.spans`):
+  ``trainer.data``, ``trainer.calibrate``, ``trainer.gate`` (a
+  sensitivity-gate refresh), ``trainer.step`` with ``.dispatch`` and
+  ``.wait`` (the loss sync), and ``trainer.checkpoint``.
 """
 from __future__ import annotations
 
@@ -40,6 +44,7 @@ from repro.core.schedule import CalibrationController, PhasePlan
 from repro.data import SyntheticLM
 from repro.hw import Fleet, VariationModel
 from repro.models.model import Model
+from repro.runtime.spans import Spans
 from repro.training.steps import StepCache, init_train_state
 
 
@@ -114,6 +119,7 @@ class Trainer:
         self._gates: Dict[int, Tuple[int, np.ndarray]] = {}  # phase -> (epoch, mask)
         self._gate_refreshes = 0
         self._gate_events: List[Tuple[int, int]] = []
+        self.spans = Spans()
 
     # ------------------------------------------------------------------
     def _state_like(self):
@@ -210,10 +216,11 @@ class Trainer:
             return cached[1]
         from repro.search import sensitivity
 
-        mask = sensitivity.backward_gate(
-            self.model, state["params"], batch, self.approx,
-            frac=phase.gate_frac, seed=self.seed, fns=self.steps,
-        )
+        with self.spans.span("trainer.gate"):
+            mask = sensitivity.backward_gate(
+                self.model, state["params"], batch, self.approx,
+                frac=phase.gate_frac, seed=self.seed, fns=self.steps,
+            )
         self._gates[index] = (epoch, mask)
         self._gate_refreshes += 1
         self._gate_events.append((step, int(mask.sum())))
@@ -245,7 +252,8 @@ class Trainer:
                 if self.fault_hook is not None:
                     self.fault_hook(step)
                 rng = jax.random.fold_in(jax.random.PRNGKey(self.seed + 17), step)
-                batch = self.data.batch_at(step)
+                with self.spans.span("trainer.data"):
+                    batch = self.data.batch_at(step)
                 # variation-aware phase: this step's device instance (a
                 # runtime pytree — switching chips never retraces)
                 cur_index, cur_phase, cur_sip = self.plan.phase_at(step)
@@ -253,13 +261,14 @@ class Trainer:
                 chip_key = step % cur_phase.fleet if chip is not None else -1
                 t0 = time.perf_counter()
                 if self.controller.begin_step(step):
-                    cal = self.steps.calibration(chip_aware=chip is not None)
-                    state, cmetrics = (
-                        cal(state, batch, rng, chip)
-                        if chip is not None
-                        else cal(state, batch, rng)
-                    )
-                    closs = float(cmetrics["loss"])
+                    with self.spans.span("trainer.calibrate"):
+                        cal = self.steps.calibration(chip_aware=chip is not None)
+                        state, cmetrics = (
+                            cal(state, batch, rng, chip)
+                            if chip is not None
+                            else cal(state, batch, rng)
+                        )
+                        closs = float(cmetrics["loss"])
                     # keyed on the chip: the adaptive policy must compare
                     # same-chip losses (fleet spread is not drift)
                     self.controller.record(step, closs, key=chip_key)
@@ -277,8 +286,11 @@ class Trainer:
                     args.append(chip)
                 if gate is not None:
                     args.append(gate)
-                state, metrics = fn(*args)
-                loss = float(metrics["loss"])
+                with self.spans.span("trainer.step"):
+                    with self.spans.span("trainer.step.dispatch"):
+                        state, metrics = fn(*args)
+                    with self.spans.span("trainer.step.wait"):
+                        loss = float(metrics["loss"])
                 dt = time.perf_counter() - t0
                 if not np.isfinite(loss):
                     raise FloatingPointError(f"non-finite loss at step {step}")
@@ -306,7 +318,8 @@ class Trainer:
                 if self.log_every and step % self.log_every == 0:
                     print(f"[{label}] step {step} loss {loss:.4f} ({dt*1e3:.0f} ms)")
                 if (step + 1) % self.tcfg.checkpoint_every == 0 or step + 1 == total:
-                    self._save(step + 1, state)
+                    with self.spans.span("trainer.checkpoint"):
+                        self._save(step + 1, state)
                 step += 1
             except (FloatingPointError, RuntimeError) as e:  # device loss etc.
                 restarts += 1

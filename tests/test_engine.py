@@ -410,3 +410,104 @@ def test_static_baseline_reports_compile_separately():
     assert sorted(rep["outputs"]) == [q.rid for q in queue]
     for q in queue:
         assert len(rep["outputs"][q.rid]) == q.max_new_tokens
+
+
+# ---------------------------------------------------------------------------
+# Spans: the engine's timers are the span totals
+# ---------------------------------------------------------------------------
+
+
+def _requests(cfg, rid0, lengths, gen=3):
+    return [
+        Request(rid=rid0 + i, prompt=_prompt(cfg, n, seed=rid0 + i),
+                max_new_tokens=gen)
+        for i, n in enumerate(lengths)
+    ]
+
+
+@pytest.mark.slow
+def test_engine_timers_are_span_totals():
+    from repro.runtime.spans import delta
+
+    cfg, m, params = _model("qwen2.5-3b")
+    eng = Engine(m, params, n_slots=2, max_seq=48, min_bucket=8)
+    warm = _requests(cfg, 0, (5, 12))  # buckets 8 and 16: every graph traces
+    eng.run(warm)
+    before, steps0 = eng.spans.snapshot(), eng.decode_steps
+    queue = _requests(cfg, 100, (4, 7, 11, 14, 3))
+    eng.run(queue)
+    snap = eng.spans.snapshot()
+    spans = snap["spans"]
+
+    # the timers read the totals; a call that traced is in compile_s only
+    assert eng.prefill_s == spans["engine.prefill"]["total_s"]
+    assert eng.decode_s == spans["engine.decode"]["total_s"]
+    assert eng.compile_s == spans["engine.compile"]["total_s"]
+    assert eng.compile_s == sum(s for _, s in eng.compiles)
+    compiled = [k[0] for k, _ in eng.compiles]
+    assert sorted(set(compiled)) == ["decode", "prefill", "reset"]
+    assert spans["engine.compile"]["count"] == len(compiled)
+    assert (spans["engine.prefill"]["count"] + compiled.count("prefill")
+            == len(warm) + len(queue))
+    assert (spans["engine.decode"]["count"] + compiled.count("decode")
+            == eng.decode_steps)
+    assert (spans["engine.reset"]["count"] + compiled.count("reset")
+            == len(eng.results))
+    assert sum(r["prefill_s"] for r in eng.results.values()) == pytest.approx(
+        eng.prefill_s, rel=1e-9)
+
+    # the second run is warm: its change holds no compile, one decode span
+    # per decode step and one reset per finished request
+    d = delta(before, snap)
+    assert "engine.compile" not in d["spans"]
+    assert d["spans"]["engine.decode"]["count"] == eng.decode_steps - steps0
+    assert d["spans"]["engine.reset"]["count"] == len(queue)
+    assert d["spans"]["engine.prefill"]["count"] == len(queue)
+    assert d["counts"]["engine.admitted"] == len(queue)
+    assert d["counts"]["engine.scanned"] >= len(queue)
+    for kind in ("prefill", "decode", "reset"):
+        s = d["spans"][f"engine.{kind}"]
+        kids = (d["spans"][f"engine.{kind}.dispatch"]["total_s"]
+                + d["spans"][f"engine.{kind}.wait"]["total_s"])
+        assert s["self_s"] == pytest.approx(s["total_s"] - kids, abs=1e-9)
+    # the admission loop's self time leaves out the prefills it makes
+    admit = d["spans"]["engine.admit"]
+    assert 0.0 <= admit["self_s"]
+    assert admit["self_s"] < admit["total_s"] - d["spans"]["engine.prefill"]["total_s"]
+    assert d["spans"]["engine.step"]["count"] == d["spans"]["engine.admit"]["count"]
+
+
+@pytest.mark.slow
+def test_engine_spans_in_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    cfg, m, params = _model("qwen2.5-3b")
+    eng = Engine(m, params, n_slots=2, max_seq=48, min_bucket=8)
+    eng.run(_requests(cfg, 0, (5,)))  # compile outside the trace
+    decodes0 = eng.spans.total("engine.decode"), eng.decode_steps
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(_requests(cfg, 10, (6, 3)))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    events.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
+
+    def inside(inner, outer):
+        return all(any(a <= s and e <= b for a, b, _ in events[outer])
+                   for s, e, _ in events[inner])
+
+    assert len(events["engine.decode"]) == eng.decode_steps - decodes0[1]
+    assert eng.spans.total("engine.decode") > decodes0[0]
+    assert inside("engine.decode", "engine.step")
+    assert inside("engine.decode.wait", "engine.decode")
+    assert inside("engine.admit", "engine.step")
+    assert inside("engine.prefill", "engine.admit")
+    assert {(s["rid"], s["bucket"]) for _, _, s in events["engine.prefill"]} == {
+        (10, 8), (11, 8)}

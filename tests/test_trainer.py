@@ -224,3 +224,17 @@ def test_restart_mid_phase_resumes_phase_and_calibration_state(tmp_path):
         assert abs(calib_a[s] - calib_b[s]) < 1e-4
     # converges to the same trajectory
     assert abs(rep_a.losses[-1] - rep_b.losses[-1]) < 1e-4
+
+
+def test_spans_count_steps_and_calibrations(tmp_path):
+    tr = _mk(tmp_path, total_steps=6, inject_steps=6, finetune_steps=0)
+    rep = tr.run()
+    spans = tr.spans.snapshot()["spans"]
+    assert spans["trainer.step"]["count"] == len(rep.losses) == 6
+    for child in ("trainer.step.dispatch", "trainer.step.wait", "trainer.data"):
+        assert spans[child]["count"] == 6
+    assert spans["trainer.calibrate"]["count"] == rep.calibrations == 2
+    assert spans["trainer.checkpoint"]["count"] == 2   # steps 3 and 6
+    # a step's span is its dispatch and its loss sync: it leaves out the
+    # calibration that TrainReport.step_times holds
+    assert spans["trainer.step"]["total_s"] < sum(rep.step_times)
