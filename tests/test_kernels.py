@@ -13,7 +13,7 @@ from repro.kernels import ref
 from repro.kernels.analog_matmul import analog_matmul
 from repro.kernels.approx_mult import approx_mult_matmul
 from repro.kernels.log_matmul import log_matmul
-from repro.kernels.sc_matmul import sc_matmul_packed
+from repro.kernels import sc_matmul as SC
 
 
 # ---------------------------------------------------------------------------
@@ -109,22 +109,52 @@ def test_approx_mul_error_bound(a, b, p):
 # Stochastic-computing kernel
 # ---------------------------------------------------------------------------
 
-SC_SHAPES = [(4, 8, 4), (20, 33, 17), (64, 64, 64)]
+# (K, N) pairs, none a multiple of the 16-wide test tiles but the last;
+# rows at decode and prefill sizes, on the tiles (8 pads to the kernel's
+# 32-row minimum) and off them (20, 130), so row padding is checked too
+SC_KN = [(8, 4), (33, 17), (64, 64)]
+SC_ROWS = [8, 20, 128, 130]
+SC_BLOCKS = dict(block_m=16, block_n=16, block_k=16)
 
 
-@pytest.mark.parametrize("M,K,N", SC_SHAPES)
-@pytest.mark.parametrize("bits", [32, 64])
-def test_sc_bit_exact_vs_ref(M, K, N, bits):
-    key = jax.random.PRNGKey(M * N)
-    xp = jax.random.uniform(key, (M, K))
-    wp = jax.random.uniform(jax.random.fold_in(key, 1), (K, N))
+def _sc_words(M, K, N, bits, scale, seed):
+    """Packed streams of uniform probabilities times ``scale``: at 0.05 (as
+    under an sc gain of 0.25) the OR over K is far from saturated, so a
+    dropped or shifted bit plane shows in the popcounts."""
+    key = jax.random.PRNGKey(seed)
+    xp = jax.random.uniform(key, (M, K)) * scale
     ux = jax.random.uniform(jax.random.fold_in(key, 2), (K, bits))
     uw = jax.random.uniform(jax.random.fold_in(key, 3), (K, bits))
     xbits = ref.sc_pack_streams(xp, ux)
-    wbits = ref.sc_pack_streams(wp, uw[:, None, :])
-    got = sc_matmul_packed(xbits, wbits, bits, interpret=True, block_m=16, block_n=16, block_k=16)
-    want = ref.sc_matmul_packed_ref(xbits, wbits) / bits
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    wbits = [
+        ref.sc_pack_streams(
+            jax.random.uniform(jax.random.fold_in(key, 10 + i), (K, N)) * scale,
+            uw[:, None, :],
+        )
+        for i in range(2)
+    ]
+    return xbits, wbits
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+@pytest.mark.parametrize("scale", [1.0, 0.05], ids=["dense", "sparse"])
+@pytest.mark.parametrize("M", SC_ROWS)
+@pytest.mark.parametrize("K,N", SC_KN)
+@pytest.mark.parametrize("bits", [32, 64])
+def test_sc_bit_exact_vs_ref(bits, K, N, M, scale, fused):
+    xbits, (wp, wn) = _sc_words(M, K, N, bits, scale, seed=M * N + K)
+    want_p = np.asarray(ref.sc_matmul_packed_ref(xbits, wp)) / bits
+    if not fused:
+        got = SC.sc_matmul_packed(xbits, wp, bits, interpret=True, **SC_BLOCKS)
+        np.testing.assert_array_equal(np.asarray(got), want_p)
+        return
+    want_n = np.asarray(ref.sc_matmul_packed_ref(xbits, wn)) / bits
+    pre = jax.random.uniform(jax.random.PRNGKey(K), (M, 1), minval=0.5, maxval=2.0)
+    got = SC.sc_matmul_packed_fused(
+        xbits, wp, wn, bits, pre, jnp.bfloat16, interpret=True, **SC_BLOCKS
+    )
+    want = ((want_p - want_n) * np.asarray(pre)).astype(jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_sc_converges_with_stream_length():

@@ -37,6 +37,16 @@ SHAPES = [
 ]
 IDS = [f"M{m}-K{k}-N{n}" for m, k, n in SHAPES]
 
+# the training cell's rows (batch 2 x 256) at its projections and head
+TRAIN_M = 512
+SC_SHAPES = SHAPES + [
+    (TRAIN_M, D, D),
+    (TRAIN_M, D, FF),
+    (TRAIN_M, FF, D),
+    (TRAIN_M, D, VOCAB),
+]
+SC_IDS = [f"M{m}-K{k}-N{n}" for m, k, n in SC_SHAPES]
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -73,18 +83,36 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("M,K,N", SHAPES, ids=IDS)
+@pytest.mark.parametrize("M,K,N", SC_SHAPES, ids=SC_IDS)
 def test_sc_compiles(one_chip, M, K, N):
     # split-unipolar: both signed halves are concatenated along K
     xb = ((M, 2 * K, 1), jnp.uint32)
     wb = ((2 * K, N, 1), jnp.uint32)
-    _compile(lambda x, w: SC.sc_matmul_packed(x, w, 32), one_chip, xb, wb)
-    _compile(
+    plain = _compile(lambda x, w: SC.sc_matmul_packed(x, w, 32), one_chip, xb, wb)
+    fused = _compile(
         lambda x, wp, wn: SC.sc_matmul_packed_fused(
             x, wp, wn, 32, jnp.float32(0.5), jnp.bfloat16
         ),
         one_chip, xb, wb, wb,
     )
+    # the kernel's name is in the compiled program at every row count
+    assert "sc_matmul_mxu" in plain.as_text()
+    assert "sc_matmul_fused_mxu" in fused.as_text()
+
+
+@pytest.mark.parametrize("words", [2, 4])
+def test_sc_mxu_compiles_long_streams(one_chip, words):
+    # 64- and 128-bit streams: word planes are a grid axis of the
+    # kernel, so its VMEM does not grow with the stream length
+    xb = ((TRAIN_M, 2 * D, words), jnp.uint32)
+    wb = ((2 * D, D, words), jnp.uint32)
+    fused = _compile(
+        lambda x, wp, wn: SC.sc_matmul_packed_fused(
+            x, wp, wn, 32 * words, jnp.float32(0.5), jnp.bfloat16
+        ),
+        one_chip, xb, wb, wb,
+    )
+    assert "sc_matmul_fused_mxu" in fused.as_text()
 
 
 @pytest.mark.parametrize("M,K,N", SHAPES, ids=IDS)
