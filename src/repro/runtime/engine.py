@@ -57,6 +57,7 @@ measures the engine against it.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import queue as _pyqueue
 import time
 from collections import deque
@@ -263,6 +264,67 @@ class _Lane:
         return sum(s is not None for s in self.slots)
 
 
+LaneKey = Tuple[ApproxConfig, bool]  # (Engine._lane_key, switch): _lane_for's args
+
+
+class PendingQueue:
+    """The engine's queue of submitted requests: one FIFO per lane key.
+
+    Each entry is ``(seq, req, approx)``, ``seq`` a submission counter, so
+    iteration merges the keys back into submission order and yields
+    ``(req, approx)`` as one FIFO would.  ``Engine.step`` admits from the
+    heads (:meth:`heads`, :meth:`popleft`): it stops looking at a key at
+    its first request that cannot be placed, so admission examines the
+    requests it admits plus one head per key, never the whole backlog.
+    """
+
+    def __init__(self):
+        self._queues: Dict[LaneKey, deque] = {}
+        self._seq = 0
+        self._n = 0
+
+    def append(self, key: LaneKey, req: Request, approx: ApproxConfig) -> None:
+        q = self._queues.get(key)
+        if q is None:
+            q = self._queues[key] = deque()
+        q.append((self._seq, req, approx))
+        self._seq += 1
+        self._n += 1
+
+    def __len__(self) -> int:  # also the queue's truth value
+        return self._n
+
+    def __iter__(self):
+        for _, req, approx in heapq.merge(
+            *self._queues.values(), key=lambda e: e[0]
+        ):
+            yield req, approx
+
+    def clear(self) -> None:
+        self._queues.clear()
+        self._n = 0
+
+    def heads(self) -> List[Tuple[int, LaneKey]]:
+        """A heap of ``(seq, key)``, one entry per non-empty key, ``seq``
+        that of the key's oldest request."""
+        h = [(q[0][0], key) for key, q in self._queues.items()]
+        heapq.heapify(h)
+        return h
+
+    def popleft(
+        self, key: LaneKey
+    ) -> Tuple[Request, ApproxConfig, Optional[int]]:
+        """Take the key's oldest request; also return the ``seq`` of its
+        next one (None when the key's queue is now empty)."""
+        q = self._queues[key]
+        _, req, approx = q.popleft()
+        self._n -= 1
+        if not q:
+            del self._queues[key]
+            return req, approx, None
+        return req, approx, q[0][0]
+
+
 class Engine:
     """Continuous-batching serving engine over one model + params.
 
@@ -273,9 +335,10 @@ class Engine:
     ``stream(rid, token, done)``.
 
     ``spans`` (:mod:`repro.runtime.spans`) times the engine's own work:
-    ``engine.step``; ``engine.admit`` (the admission loop, whose self
-    time is the scan of the pending queue; counters ``engine.scanned``
-    and ``engine.admitted``); every compiled call as ``engine.<kind>``
+    ``engine.step``; ``engine.admit`` (the admission loop; counters
+    ``engine.scanned``, the queue heads it examined: each request
+    admitted plus one per lane key that stopped at a request it could not
+    place, and ``engine.admitted``); every compiled call as ``engine.<kind>``
     (``prefill``, ``decode``, ``reset``, ``recalibrate``, ``probe``, or
     ``compile`` for a call that traced) with children ``.dispatch`` and
     ``.wait``; ``engine.fetch`` (logits to the host) and
@@ -440,7 +503,7 @@ class Engine:
         # (serving config, lane index): with a fleet, one emulated config
         # spreads over several lanes — one per bound chip
         self.lanes: Dict[Tuple[ApproxConfig, int], _Lane] = {}
-        self.pending: deque = deque()
+        self.pending = PendingQueue()
         self.results: Dict[int, Dict[str, Any]] = {}
 
         self._rng = jax.random.PRNGKey(seed)
@@ -466,8 +529,10 @@ class Engine:
                 f"gen({req.max_new_tokens}) exceeds max_seq={self.max_seq}"
             )
         # resolve once here (unknown backends fail at submit, not in the
-        # loop); the queue carries (request, lane-key) pairs
-        self.pending.append((req, resolve_approx(req, self.approx_base)))
+        # loop); the queue files the request under its lane key
+        approx = resolve_approx(req, self.approx_base)
+        key = (self._lane_key(approx), self.switch and approx.active)
+        self.pending.append(key, req, approx)
 
     @property
     def prefill_s(self) -> float:
@@ -1054,6 +1119,33 @@ class Engine:
         return events
 
     # -- the engine loop -------------------------------------------------
+    def _admit_pending(self) -> List[Dict[str, Any]]:
+        """Admit queued requests in submission order while their lanes
+        have room.  A key whose oldest request finds no free slot is done
+        for this step: only an admission into that key's own lane (a
+        request finishing at its first token) could free one, and
+        ``_max_lanes`` does not change here.  So the ``_admit`` calls, and
+        the rng ticks they take, come in the order a scan of the whole
+        queue would make them."""
+        events: List[Dict[str, Any]] = []
+        heads = self.pending.heads()
+        scanned = admitted = 0
+        while heads:
+            _, key = heapq.heappop(heads)
+            scanned += 1
+            lane = self._lane_for(*key)
+            free = lane.free_slots() if lane is not None else []
+            if not free:
+                continue
+            req, approx, nxt = self.pending.popleft(key)
+            events += self._admit(lane, free[0], req, approx)
+            admitted += 1
+            if nxt is not None:
+                heapq.heappush(heads, (nxt, key))
+        self.spans.count("engine.scanned", scanned)
+        self.spans.count("engine.admitted", admitted)
+        return events
+
     def step(self) -> List[Dict[str, Any]]:
         """One engine iteration: admit what fits, then decode every lane
         (running each chip-bound lane's recalibration first when its
@@ -1065,22 +1157,7 @@ class Engine:
             events: List[Dict[str, Any]] = []
             self.apply_pushes()
             with self.spans.span("engine.admit"):
-                deferred: deque = deque()
-                scanned = len(self.pending)
-                while self.pending:
-                    req, approx = self.pending.popleft()
-                    lane = self._lane_for(
-                        self._lane_key(approx),
-                        switch=self.switch and approx.active,
-                    )
-                    free = lane.free_slots() if lane is not None else []
-                    if free:
-                        events += self._admit(lane, free[0], req, approx)
-                    else:
-                        deferred.append((req, approx))
-                self.spans.count("engine.scanned", scanned)
-                self.spans.count("engine.admitted", scanned - len(deferred))
-                self.pending = deferred
+                events += self._admit_pending()
 
             active = sum(l.n_active() for l in self.lanes.values())
             capacity = max(1, self.n_slots * len(self.lanes))

@@ -6,6 +6,7 @@ engine generates greedily, then the full-sequence forward on
 prompt + generated must reproduce the engine's per-step logits.
 """
 import dataclasses
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -511,3 +512,187 @@ def test_engine_spans_in_profiler_trace(tmp_path):
     assert inside("engine.prefill", "engine.admit")
     assert {(s["rid"], s["bucket"]) for _, _, s in events["engine.prefill"]} == {
         (10, 8), (11, 8)}
+
+
+# ---------------------------------------------------------------------------
+# Admission from per-lane-key queues: the order of a whole-queue scan
+# ---------------------------------------------------------------------------
+
+
+class _ScanEngine(Engine):
+    """The oracle: one FIFO of every pending request, all of it popped
+    each step, what fits admitted and the rest pushed back in order."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.pending = deque()
+
+    def submit(self, req):
+        self.pending.append((req, resolve_approx(req, self.approx_base)))
+
+    def _admit_pending(self):
+        events, deferred = [], deque()
+        while self.pending:
+            req, approx = self.pending.popleft()
+            lane = self._lane_for(
+                self._lane_key(approx), switch=self.switch and approx.active
+            )
+            free = lane.free_slots() if lane is not None else []
+            if free:
+                events += self._admit(lane, free[0], req, approx)
+            else:
+                deferred.append((req, approx))
+        self.pending = deferred
+        return events
+
+
+def _admission_log(eng):
+    """Record, step by step, each admission's (rid, lane, slot)."""
+    log = []
+    admit, step = eng._admit, eng.step
+
+    def rec_admit(lane, slot, req, approx=None):
+        log[-1].append((req.rid, lane.key, slot))
+        return admit(lane, slot, req, approx)
+
+    def rec_step():
+        log.append([])
+        return step()
+
+    eng._admit, eng.step = rec_admit, rec_step
+    return log
+
+
+def _mixed_queue(cfg, spec):
+    return [
+        Request(rid=i, prompt=_prompt(cfg, 3 + i % 6, seed=40 + i),
+                max_new_tokens=gen, temperature=0.8 if i % 3 == 0 else 0.0,
+                **kw)
+        for i, (gen, kw) in enumerate(spec)
+    ]
+
+
+_EXACT, _SC, _AM = {}, {"backend": "sc"}, {"backend": "approx_mult"}
+_QUEUES = {
+    # three lane keys; the exact key saturates its two slots while sc and
+    # approx_mult have room, and requests of one token free their slot
+    # inside admission
+    "mixed": (
+        {},
+        [(3, _EXACT), (1, _EXACT), (2, _SC), (4, _EXACT), (1, _EXACT),
+         (1, _AM), (2, _EXACT), (1, _SC), (3, _AM), (3, _SC), (1, _EXACT),
+         (2, _AM), (1, _SC), (2, _EXACT)],
+    ),
+    # switch: every emulated map shares one merged key, exact its own
+    "switch": (
+        {"switch": True},
+        [(2, {"site_backends": (("attn_*", "log_mult"),)}), (1, _EXACT),
+         (3, {"site_backends": (("mlp_*", "approx_mult"),)}), (2, _EXACT),
+         (1, {"backend": "log_mult"}), (2, _EXACT),
+         (2, {"site_backends": (("attn_q", "sc"),)}), (1, _EXACT),
+         (3, {"backend": "approx_mult"})],
+    ),
+    # fleet: one emulated key grows a lane per chip; exact keeps one lane
+    "fleet": (
+        {"fleet": 2},
+        [(2, {"backend": "log_mult"}), (3, {"backend": "log_mult"}),
+         (1, _EXACT), (2, {"backend": "log_mult"}), (1, {"backend": "log_mult"}),
+         (2, _EXACT), (2, {"backend": "log_mult"}), (1, _EXACT)],
+    ),
+}
+
+
+@pytest.mark.parametrize("queue_name", sorted(_QUEUES))
+def test_engine_admission_order_matches_queue_scan(queue_name):
+    from repro.hw import Fleet
+    from repro.training.steps import CompiledFnCache
+
+    cfg, m, params = _model("qwen2.5-3b")
+    kwargs, spec = _QUEUES[queue_name]
+    queue = _mixed_queue(cfg, spec)
+    fns = CompiledFnCache()  # compile once for both engines
+
+    def make(cls):
+        kw = dict(kwargs)
+        if "fleet" in kw:  # a fleet of its own: serving ages its chips
+            kw["fleet"] = Fleet(kw["fleet"], seed=3)
+        slots = 1 if "fleet" in kw else 2
+        return cls(m, params, n_slots=slots, max_seq=16, min_bucket=8,
+                   seed=7, fns=fns, **kw)
+
+    new, old = make(Engine), make(_ScanEngine)
+    log_new, log_old = _admission_log(new), _admission_log(old)
+    res_new, res_old = new.run(queue), old.run(queue)
+
+    assert log_new == log_old
+    assert len({rid for step in log_new for rid, _, _ in step}) == len(queue)
+    assert sum(1 for step in log_new if len(step) > 1) > 0
+    assert {rid: r["tokens"] for rid, r in res_new.items()} == {
+        rid: r["tokens"] for rid, r in res_old.items()}
+    assert {rid: r["chip"] for rid, r in res_new.items()} == {
+        rid: r["chip"] for rid, r in res_old.items()}
+    assert new._tick == old._tick
+    if queue_name == "fleet":
+        assert sum(1 for (a, _) in new.lanes if a.active) == 2
+
+
+def test_engine_scan_examines_heads_not_the_backlog():
+    cfg, m, params = _model("qwen2.5-3b")
+    eng = Engine(m, params, n_slots=4, max_seq=16, min_bucket=8)
+    queue = [
+        Request(rid=i, prompt=_prompt(cfg, 3 + i % 4, seed=i),
+                max_new_tokens=1 + i % 3,
+                backend=("exact", "log_mult")[i % 2])
+        for i in range(200)
+    ]
+    for r in queue:
+        eng.submit(r)
+    n_keys = 2
+    admitted_total = 0
+    while eng.pending or any(l.n_active() for l in eng.lanes.values()):
+        waiting = len(eng.pending)
+        before = eng.spans.snapshot()["counts"]
+        eng.step()
+        after = eng.spans.snapshot()["counts"]
+        scanned = after["engine.scanned"] - before.get("engine.scanned", 0)
+        admitted = after["engine.admitted"] - before.get("engine.admitted", 0)
+        assert admitted <= scanned <= admitted + n_keys
+        assert len(eng.pending) == waiting - admitted
+        if waiting > 2 * eng.n_slots * n_keys + n_keys:
+            assert scanned < waiting
+        admitted_total += admitted
+    assert admitted_total == len(queue)
+    assert sorted(eng.results) == list(range(len(queue)))
+
+
+def test_pending_queue_interface():
+    from repro.runtime.engine import PendingQueue
+
+    a = ApproxConfig()
+    b = ApproxConfig(backend=Backend.LOG_MULT, mode=TrainMode.MODEL)
+    reqs = [Request(rid=i, prompt=(1,)) for i in range(7)]
+    keys = [(a, False), (b, False), (b, False), (a, False), (b, True),
+            (a, False), (b, False)]
+    pq = PendingQueue()
+    assert len(pq) == 0 and not pq and list(pq) == []
+    for r, k in zip(reqs, keys):
+        pq.append(k, r, k[0])
+    assert len(pq) == 7 and pq
+    # one FIFO's order across the keys, each request with its config
+    assert [(r.rid, ap) for r, ap in pq] == [
+        (i, k[0]) for i, k in enumerate(keys)]
+    # admission pops by key from a heap of the keys' oldest requests
+    heads = pq.heads()
+    assert sorted(heads) == [(0, (a, False)), (1, (b, False)), (4, (b, True))]
+    req, ap, nxt = pq.popleft((b, False))
+    assert (req.rid, ap, nxt) == (1, b, 2) and len(pq) == 6
+    req, _, nxt = pq.popleft((b, True))
+    assert req.rid == 4 and nxt is None
+    assert [r.rid for r, _ in pq] == [0, 2, 3, 5, 6]
+    assert {k for _, k in pq.heads()} == {(a, False), (b, False)}
+    pq.clear()
+    assert len(pq) == 0 and not pq and list(pq) == [] and pq.heads() == []
+    # submission order carries on after a clear
+    pq.append((b, False), reqs[0], b)
+    pq.append((a, False), reqs[1], a)
+    assert [r.rid for r, _ in pq] == [0, 1]

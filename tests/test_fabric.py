@@ -193,6 +193,47 @@ def test_fabric_replica_death_rehomes_without_token_loss(tiny, fns, probe):
         fab.shutdown()
 
 
+def test_fabric_replica_death_rehomes_two_lane_keys_in_order(tiny, fns):
+    """A dead replica's engine queue holds two lane keys, interleaved: the
+    survivor receives its requests in submission order across the keys,
+    then the in-flight ones, and every one finishes in full."""
+    cfg, model, params = tiny
+    fab = Fabric(model, params, replicas=2, n_slots=1, max_seq=32,
+                 seed=0, fns=fns)
+    try:
+        queue = _queue(cfg, 8, gen=(3, 6))  # exact, log_mult, exact, ...
+        victim, survivor = fab.workers
+        for r in queue:
+            assert victim.enqueue(r)
+        victim.run_once()  # one slot per key: two admitted, six queued
+        eng = victim.engine
+        queued = [req.rid for req, _ in eng.pending]
+        assert queued == [r.rid for r in queue[2:]]
+        assert {approx.active for _, approx in eng.pending} == {False, True}
+        in_flight = [st.req.rid for lane in eng.lanes.values()
+                     for st in lane.slots if st is not None]
+        assert sorted(in_flight) == [0, 1]
+
+        rehomed = []
+        enqueue = survivor.enqueue
+
+        def record(req):
+            rehomed.append(req.rid)
+            return enqueue(req)
+
+        survivor.enqueue = record
+        fab.kill_replica(0)
+        fab.pump()
+        assert rehomed == queued + in_flight
+        assert not eng.pending and len(eng.pending) == 0
+        res = fab.run()
+        assert set(res) == {r.rid for r in queue}
+        for r in queue:
+            assert len(res[r.rid]["tokens"]) == r.max_new_tokens, r.rid
+    finally:
+        fab.shutdown()
+
+
 def test_fabric_recal_push_applies_at_step_boundary(tiny, fns, probe):
     cfg, model, params = tiny
     fleet = Fleet(1, seed=2)
